@@ -19,7 +19,13 @@ is bit-identical to uncached ``fsparse`` dispatch before timing.
 
 Cache state (plan caches, the persistent compilation cache config) is
 process-global, so both phases run as fresh subprocesses of ``run``;
-rows are re-emitted in the parent for the ``--json`` collector.
+rows are re-emitted in the parent for the ``--json`` collector.  The
+parent never touches a JAX backend (each child in turn holds the
+device), and both children share JAX's compile cache
+(``JAX_COMPILATION_CACHE_DIR``, else the checkout's fixed
+``.jax_cache``) — so "cold" means cold plans, not necessarily cold
+compiles.  The plan pickles go to the fixed ``.bench_cache/serving``,
+emptied before the cold phase.
 """
 from __future__ import annotations
 
@@ -27,7 +33,6 @@ import os
 import shutil
 import subprocess
 import sys
-import tempfile
 
 THREADS = 4
 REQUESTS = 8
@@ -123,18 +128,22 @@ def _inner(phase: str, scale: float, cache_dir: str,
     return rows_out
 
 
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+#: fixed plan-pickle directory shared by the cold and restart children
+PLAN_DIR = os.path.join(_ROOT, ".bench_cache", "serving")
+
+
 def _launch(phase: str, scale: float, cache_dir: str,
             threads: int, requests: int) -> str:
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = (
-        os.path.join(root, "src") + os.pathsep + env.get("PYTHONPATH", "")
+        os.path.join(_ROOT, "src") + os.pathsep + env.get("PYTHONPATH", "")
     )
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks.bench_serving",
          "--phase", phase, "--scale", str(scale), "--cache-dir", cache_dir,
          "--threads", str(threads), "--requests", str(requests)],
-        env=env, capture_output=True, text=True, timeout=900, cwd=root,
+        env=env, capture_output=True, text=True, timeout=900, cwd=_ROOT,
     )
     if out.returncode != 0:
         raise RuntimeError(
@@ -146,7 +155,9 @@ def _launch(phase: str, scale: float, cache_dir: str,
 
 def run(scale: float = 0.1, threads: int = THREADS,
         requests: int = REQUESTS):
-    from .common import row
+    from .common import require_no_backend, row
+
+    require_no_backend("bench_serving")
 
     def _coerce(v: str):
         for cast in (int, float):
@@ -169,13 +180,9 @@ def run(scale: float = 0.1, threads: int = THREADS,
             parsed.append((name, float(us), kv))
         return parsed
 
-    cache_dir = tempfile.mkdtemp(prefix="repro-serving-bench-")
-    try:
-        cold = _parse(_launch("cold", scale, cache_dir, threads, requests))
-        restart = _parse(
-            _launch("restart", scale, cache_dir, threads, requests))
-    finally:
-        shutil.rmtree(cache_dir, ignore_errors=True)
+    shutil.rmtree(PLAN_DIR, ignore_errors=True)
+    cold = _parse(_launch("cold", scale, PLAN_DIR, threads, requests))
+    restart = _parse(_launch("restart", scale, PLAN_DIR, threads, requests))
 
     cold_us = {name: us for name, us, _ in cold}
     out_rows = []

@@ -14,10 +14,12 @@ the symbolic phase carries two size-L/p sorts plus the all_to_all
 routing analysis, while the cached fill is one bucket scatter, one
 all_to_all on values, and one gather+scatter.
 
-The device count must be fixed before jax initializes, so ``run``
-re-launches itself in a subprocess with
+The device count must be fixed before jax initializes.  On the CPU,
+``run`` re-launches itself in a CPU-only subprocess with
 ``XLA_FLAGS=--xla_force_host_platform_device_count`` unless the
-current process already sees multiple devices.
+current process already sees multiple devices; on one accelerator it
+refuses (the sharded path needs at least 2 devices, and a child could
+not reach the device this process holds).
 """
 from __future__ import annotations
 
@@ -73,12 +75,19 @@ def _inner(scale: float, method: str) -> list[dict]:
 def run(scale: float = 0.1, method: str = "jnp", devices: int = DEVICES):
     import jax
 
-    if len(jax.devices()) > 1:
+    devs = jax.devices()
+    if len(devs) > 1:
         return _inner(scale, method)
-    # single-device process: re-launch with a forced host-device count
-    # (the flag must be set before jax initializes — dry-run contract)
+    if devs[0].platform != "cpu":
+        raise RuntimeError(
+            f"shard_reassemble needs at least 2 devices; found 1 "
+            f"{devs[0].platform} device ({devs[0].device_kind})"
+        )
+    # single CPU device: re-launch on the CPU with a forced host-device
+    # count (the flag must be set before jax initializes)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = (
         os.path.join(root, "src") + os.pathsep + env.get("PYTHONPATH", "")

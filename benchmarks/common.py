@@ -42,6 +42,20 @@ def time_host_fn(fn, *args, warmup: int = 1, iters: int = 5) -> float:
     return times[len(times) // 2] * 1e6
 
 
+def require_no_backend(what: str) -> None:
+    """Refuse to start chip-needing children from a process that holds
+    a JAX backend: an accelerator belongs to one process at a time, so
+    such a child would fail or hang."""
+    from jax._src import xla_bridge
+
+    if xla_bridge.backends_are_initialized():
+        raise RuntimeError(
+            f"{what} starts child processes that need the device, but "
+            "this process has already initialized a JAX backend; run it "
+            "first (benchmarks.run does) or alone with --only"
+        )
+
+
 def row(name: str, us: float, **derived) -> dict:
     """Emit one CSV row; return (and collect) the machine-readable dict."""
     d = "|".join(f"{k}={v}" for k, v in derived.items())

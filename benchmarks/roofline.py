@@ -40,16 +40,27 @@ import json
 
 import jax
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+#: published per-chip peaks, keyed by ``jax.Device.device_kind``.  A
+#: device whose kind is missing here is an error, never a default.
+#: Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+#: 16 GB of HBM at 819 GB/s).
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_s": 819e9},
+}
+
+PEAK_FLOPS = DEVICE_PEAKS["TPU v5 lite"]["bf16_flops"]
+HBM_BW = DEVICE_PEAKS["TPU v5 lite"]["hbm_bytes_s"]
 ICI_BW = 50e9
 CHIPS = 256
 
-#: peak memory bandwidth per backend, GB/s.  TPU (v5e HBM) is a
-#: datasheet constant; CPU has no portable datasheet number, so the
-#: roof is measured once per process with a NumPy STREAM-triad sweep.
-BACKEND_PEAK_GBS = {"tpu": HBM_BW / 1e9}
-_MEASURED_PEAK_GBS: dict = {}
+#: the host's measured STREAM-triad bandwidth, GB/s (once per process).
+#: It is the CPU's roof, reported as ``cpu_stream_gbs`` — never as a
+#: device peak.
+_CPU_STREAM_GBS: list = []
+
+#: the achieved-fraction column that goes with each roof's name
+FRACTION_KEY = {"peak_gbs": "roofline_frac",
+                "cpu_stream_gbs": "cpu_stream_frac"}
 
 
 def measure_stream_gbs(n: int = 1 << 24, reps: int = 3) -> float:
@@ -75,31 +86,46 @@ def measure_stream_gbs(n: int = 1 << 24, reps: int = 3) -> float:
     return 3 * 8 * n / best / 1e9
 
 
-def backend_peak_gbs(backend: str | None = None) -> float:
-    """The bandwidth roof for ``backend`` (measured lazily on CPU)."""
-    if backend is None:
-        backend = jax.default_backend()
-    if backend in BACKEND_PEAK_GBS:
-        return BACKEND_PEAK_GBS[backend]
-    if backend not in _MEASURED_PEAK_GBS:
-        _MEASURED_PEAK_GBS[backend] = measure_stream_gbs()
-    return _MEASURED_PEAK_GBS[backend]
+def bandwidth_roof(device=None) -> tuple:
+    """``(name, GB/s)`` of ``device``'s bandwidth roof.
 
-
-def annotate_roofline(rows, backend: str | None = None) -> int:
-    """Add achieved-vs-peak columns to kernel rows in place.
-
-    Every row dict carrying a ``bandwidth_gbs`` value gains
-    ``peak_gbs`` (the backend's bandwidth roof) and ``roofline_frac``
-    (achieved / peak).  Returns how many rows were annotated.
+    An accelerator's roof is its published HBM peak from
+    :data:`DEVICE_PEAKS` (``"peak_gbs"``; an unknown ``device_kind``
+    raises).  The CPU's is the host's measured STREAM triad
+    (``"cpu_stream_gbs"``).
     """
-    peak = backend_peak_gbs(backend)
+    if device is None:
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        if not _CPU_STREAM_GBS:
+            _CPU_STREAM_GBS.append(measure_stream_gbs())
+        return "cpu_stream_gbs", _CPU_STREAM_GBS[0]
+    try:
+        peaks = DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peak for device_kind {device.device_kind!r} "
+            f"({device.platform}); add it to DEVICE_PEAKS with its source"
+        ) from None
+    return "peak_gbs", peaks["hbm_bytes_s"] / 1e9
+
+
+def annotate_roofline(rows, device=None) -> int:
+    """Add achieved-vs-roof columns to kernel rows in place.
+
+    Every row dict carrying a ``bandwidth_gbs`` value gains the roof
+    under its name (``peak_gbs`` on an accelerator, ``cpu_stream_gbs``
+    on the CPU) and the achieved fraction (``roofline_frac`` /
+    ``cpu_stream_frac``).  Returns how many rows were annotated.
+    """
+    name, roof = bandwidth_roof(device)
+    frac_key = FRACTION_KEY[name]
     annotated = 0
     for r in rows:
         if "bandwidth_gbs" not in r:
             continue
-        r["peak_gbs"] = round(peak, 2)
-        r["roofline_frac"] = round(float(r["bandwidth_gbs"]) / peak, 4)
+        r[name] = round(roof, 2)
+        r[frac_key] = round(float(r["bandwidth_gbs"]) / roof, 4)
         annotated += 1
     return annotated
 

@@ -1,36 +1,13 @@
-"""Cross-version jax shims and shared deprecation helpers.
+"""Shared deprecation helpers.
 
-One home for the version probes so call sites (distributed assembly,
-MoE dispatch, the ``fused=`` deprecation shims) stay in sync.
+One home for the ``fused=`` deprecation shims so every back-compat
+entry point resolves and warns the same way.
 """
 from __future__ import annotations
 
 import warnings
 
-try:  # jax >= 0.5 top-level export; 0.4.x keeps it in experimental
-    from jax import shard_map as _shard_map_impl
-except ImportError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
 from ..sparse.dispatch import method_from_fused
-
-
-def shard_map(f, *, mesh, in_specs, out_specs):
-    """shard_map without replication checking, across jax versions.
-
-    The kwarg disabling the replication check was renamed
-    ``check_rep`` (0.4.x) -> ``check_vma`` (newer); probe at call time.
-    """
-    try:
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:  # pragma: no cover - version-dependent
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
 
 
 def resolve_method_arg(fused: bool | None, method: str | None,

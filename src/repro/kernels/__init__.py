@@ -19,7 +19,7 @@ from .assembly_ops import (
     multiply_fused,
     plan_pallas,
 )
-from .common import INTERPRET
+from .common import resolve_interpret
 from .counting_sort.ops import counting_sort
 from .hist.ops import block_offsets, histogram
 from .radix_sort.ops import plan_digit_passes, radix_sort_pair
@@ -38,7 +38,6 @@ from .segment_sum.segment_sum import (
 from .spmv.ops import csc_to_ell, spmv
 
 __all__ = [
-    "INTERPRET",
     "assemble_pallas",
     "block_offsets",
     "blocked_cumsum",
@@ -58,6 +57,7 @@ __all__ = [
     "plan_digit_passes",
     "plan_pallas",
     "radix_sort_pair",
+    "resolve_interpret",
     "segment_sum_sorted",
     "spmv",
 ]

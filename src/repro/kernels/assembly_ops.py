@@ -25,10 +25,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from ..core.compat import shard_map
 from ..core.csc import CSC
 from ..sparse.dispatch import sorted_permutation
 from ..sparse.pattern import (
@@ -218,7 +217,7 @@ def _fill_sharded_pallas_jit(send_slot, perm, slot, vals, *, mesh, axis,
 
     return shard_map(
         _local,
-        mesh=mesh,
+        mesh=mesh, check_vma=False,
         in_specs=(P(axis), P(axis), P(axis), P(None, axis)),
         out_specs=P(axis),
     )(send_slot, perm, slot, vals)

@@ -2,15 +2,22 @@
 
 All kernels are written against TPU tiling constraints (last dim a
 multiple of 128 lanes, 8 sublanes) and validated on CPU with
-``interpret=True``; ``INTERPRET`` flips automatically off-TPU.
+``interpret=True``; :func:`resolve_interpret` picks the mode when a
+kernel is traced, so importing a kernel module touches no backend.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-#: run kernels in interpret mode unless a real TPU backend is present.
-INTERPRET = jax.default_backend() != "tpu"
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``interpret=None`` means interpret mode unless the default
+    backend is a TPU; an explicit flag is returned unchanged."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
 
 LANES = 128
 SUBLANES = 8

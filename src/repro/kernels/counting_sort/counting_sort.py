@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import INTERPRET, round_up
+from ..common import resolve_interpret, round_up
 
 
 def _placement_kernel(keys_ref, offsets_ref, pos_ref, *, block_t: int):
@@ -78,7 +78,7 @@ def placement(
     ``offsets``: ``[nblocks, nbins]`` from ``hist.ops.block_offsets``
     with the *same* ``block_b``.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     L = keys.shape[0]
     Lp = round_up(max(L, block_b), block_b)
     Kp = round_up(max(nbins, block_t), block_t)

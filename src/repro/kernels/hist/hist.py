@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import INTERPRET, round_up
+from ..common import resolve_interpret, round_up
 
 
 def _hist_kernel(keys_ref, out_ref, *, block_t: int):
@@ -45,7 +45,7 @@ def block_histogram(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Per-block histograms ``[nblocks, nbins_padded]`` (private counters)."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     L = keys.shape[0]
     Lp = round_up(max(L, block_b), block_b)
     Kp = round_up(max(nbins, block_t), block_t)

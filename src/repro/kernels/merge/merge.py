@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import INTERPRET, LANES, round_up
+from ..common import LANES, resolve_interpret, round_up
 from .ref import _below, search_steps
 
 
@@ -63,7 +63,7 @@ def merge_search_pallas(
     """
     if side not in ("left", "right"):
         raise ValueError(f"side must be 'left' or 'right', got {side!r}")
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     n = int(t_rows.shape[0])
     Lq = int(q_rows.shape[0])
     if n == 0 or Lq == 0:

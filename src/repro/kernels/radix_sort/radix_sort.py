@@ -22,14 +22,17 @@ VMEM so the digit stream never round-trips HBM:
   _digit_placement_kernel  the paper's placement loop
                            ``rank[jrS[k]++] = i`` decomposed as
                            global base (Part-1 offsets) + prior-equal
-                           count, both read off ONE one-hot tile: an
-                           exclusive cumsum down the block axis is the
-                           running per-digit counter, so the whole
-                           placement is O(B x T) VPU work — no
-                           [B, B] equality matrix (the counting-sort
-                           kernel's MXU trick costs O(B^2) per block,
-                           which dominates exactly when the digit's
-                           bin tile is small).
+                           count: the block is swept one 128-lane row
+                           at a time, the row's [T, 128] one-hot is
+                           scanned by a 128 x 128 triangular matmul and
+                           a per-bin carry joins the rows — O(B x T)
+                           work, no [B, B] equality matrix (the
+                           counting-sort kernel's MXU trick costs
+                           O(B^2) per block).
+
+Layouts are the ones the TPU compiler accepts: keys and positions as
+``[L / 128, 128]`` rows, per-block histograms and offsets as
+``[nblocks, Kp, 1]`` columns (bins on sublanes).
 
 Tiles adapt to the digit width: ``block_t`` shrinks to the 128-lane
 rounding of ``nbins`` so a 5-bit digit pays for one lane tile, not a
@@ -46,25 +49,27 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import INTERPRET, LANES, round_up
+from ..common import LANES, SUBLANES, resolve_interpret, round_up
 
-
-#: budget for the [block_b, block_t] one-hot work tile: 2^20 int32
-#: elements = 4 MB, leaving room for the cumsum/product temporaries and
-#: double buffering inside a 16 MB VMEM core.
-_TILE_ELEMS = 1 << 20
+#: the element-block granule: keys and positions are laid out as
+#: ``[L / 128, 128]`` rows, and a block spans whole (8, 128) int32 tiles.
+_GRANULE = SUBLANES * LANES
 
 
 def _tile_width(nbins: int, block_t: int) -> int:
-    """Lane-tile width for a digit with ``nbins`` bins: never wider than
+    """Bin-tile width for a digit with ``nbins`` bins: never wider than
     the requested ``block_t``, never narrower than one 128-lane tile."""
     return min(block_t, round_up(nbins, LANES))
 
 
-def _block_rows(block_b: int, block_t: int) -> int:
-    """Shrink the element block when the bin tile is wide so the
-    [block_b, block_t] one-hot tile stays within the VMEM budget."""
-    return min(block_b, max(1024, _TILE_ELEMS // block_t))
+def _layout(L: int, nbins: int, block_b: int, block_t: int):
+    """``(block_b, block_t, Lp, Kp)``: element block rounded to whole
+    tiles, bin tile, and the padded stream and bin lengths."""
+    block_t = _tile_width(nbins, block_t)
+    block_b = round_up(max(block_b, 1), _GRANULE)
+    Lp = round_up(max(L, block_b), block_b)
+    Kp = round_up(max(nbins, block_t), block_t)
+    return block_b, block_t, Lp, Kp
 
 
 def _extract_digit(keys, *, shift: int, mask: int, sentinel: int):
@@ -74,15 +79,30 @@ def _extract_digit(keys, *, shift: int, mask: int, sentinel: int):
     return jnp.where(keys < 0, jnp.int32(sentinel), d)
 
 
+def _tile_bins(block_t: int):
+    """Column ``[block_t, 1]`` of the bin ids of grid tile ``t``."""
+    t = pl.program_id(1)
+    return t * block_t + jax.lax.broadcasted_iota(jnp.int32, (block_t, 1), 0)
+
+
 def _digit_hist_kernel(keys_ref, out_ref, *, shift: int, mask: int,
                        block_t: int, sentinel: int):
-    """out[b, t0:t0+T] = histogram of block b's digits over bin tile t."""
-    t = pl.program_id(1)
-    d = _extract_digit(keys_ref[...], shift=shift, mask=mask,
-                       sentinel=sentinel)
-    bins = t * block_t + jax.lax.iota(jnp.int32, block_t)
-    onehot = (d[:, None] == bins[None, :]).astype(jnp.int32)
-    out_ref[...] = jnp.sum(onehot, axis=0, keepdims=True)
+    """out[b, t0:t0+T] = histogram of block b's digits over bin tile t.
+
+    The block is swept one 128-lane row at a time: each row's
+    ``[T, 128]`` one-hot (bins on sublanes, elements on lanes) is added
+    into a running tile, reduced over lanes once at the end.
+    """
+    bins = _tile_bins(block_t)
+
+    def row(r, acc):
+        d = _extract_digit(keys_ref[pl.ds(r, 1), :], shift=shift,
+                           mask=mask, sentinel=sentinel)
+        return acc + (d == bins).astype(jnp.int32)
+
+    acc = jax.lax.fori_loop(0, keys_ref.shape[0], row,
+                            jnp.zeros((block_t, LANES), jnp.int32))
+    out_ref[...] = jnp.sum(acc, axis=1, keepdims=True)
 
 
 def _digit_placement_kernel(keys_ref, offsets_ref, pos_ref, *, shift: int,
@@ -92,27 +112,38 @@ def _digit_placement_kernel(keys_ref, offsets_ref, pos_ref, *, shift: int,
     For element i with digit in this tile:
       position[i] = offsets[b, digit_i]          (global base + earlier
                                                   blocks, from Part 1)
-                  + prior_equal_in_block(i)      (exclusive cumsum of
-                                                  the one-hot column)
-    Digits outside the tile contribute zero, so summing over the grid's
-    tile axis assembles the full position — all O(B x T) per tile.
+                  + prior_equal_in_block(i)      (running count of equal
+                                                  digits before i)
+    The running count is an inclusive scan of the ``[T, 128]`` one-hot
+    of one 128-lane row — a matmul with the upper-triangular ones
+    matrix on the MXU, exact for 0/1 inputs — plus a per-bin carry over
+    earlier rows (the scan's last lane).  Digits outside the tile
+    contribute zero, so summing over the grid's tile axis assembles the
+    full position.
     """
-    t = pl.program_id(1)
-    d = _extract_digit(keys_ref[...], shift=shift, mask=mask,
-                       sentinel=sentinel)
-    bins = t * block_t + jax.lax.iota(jnp.int32, block_t)
-    onehot = (d[:, None] == bins[None, :]).astype(jnp.int32)
-    prior = jnp.cumsum(onehot, axis=0) - onehot  # exclusive: earlier equals
-    base = offsets_ref[0, :].astype(jnp.int32)
-    contrib = jnp.sum(onehot * (prior + base[None, :]), axis=1)
+    bins = _tile_bins(block_t)
+    j = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    i = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    upper = (j <= i).astype(jnp.bfloat16)
 
-    @pl.when(t == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _():
-        pos_ref[...] = contrib
+        pos_ref[...] = jnp.zeros_like(pos_ref)
 
-    @pl.when(t != 0)
-    def _():
-        pos_ref[...] = pos_ref[...] + contrib
+    def row(r, carry):
+        d = _extract_digit(keys_ref[pl.ds(r, 1), :], shift=shift,
+                           mask=mask, sentinel=sentinel)
+        onehot = d == bins
+        incl = jnp.dot(onehot.astype(jnp.bfloat16), upper,
+                       preferred_element_type=jnp.float32
+                       ).astype(jnp.int32)
+        # element i's slot: base + equal digits before it = carry+incl-1
+        slot = jnp.where(onehot, carry + incl - 1, 0)
+        pos_ref[pl.ds(r, 1), :] += jnp.sum(slot, axis=0, keepdims=True)
+        return carry + incl[:, LANES - 1:]
+
+    jax.lax.fori_loop(0, keys_ref.shape[0], row,
+                      offsets_ref[...].astype(jnp.int32))
 
 
 @functools.partial(
@@ -131,25 +162,23 @@ def digit_block_histogram(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Per-block digit histograms ``[nblocks, nbins_padded]``."""
-    interpret = INTERPRET if interpret is None else interpret
     L = keys.shape[0]
-    block_t = _tile_width(nbins, block_t)
-    block_b = _block_rows(block_b, block_t)
-    Lp = round_up(max(L, block_b), block_b)
-    Kp = round_up(max(nbins, block_t), block_t)
+    block_b, block_t, Lp, Kp = _layout(L, nbins, block_b, block_t)
     keys_p = jnp.pad(keys, (0, Lp - L), constant_values=-1)
     nblocks = Lp // block_b
-    return pl.pallas_call(
+    rows = block_b // LANES
+    hist = pl.pallas_call(
         functools.partial(
             _digit_hist_kernel, shift=shift, mask=(1 << bits) - 1,
             block_t=block_t, sentinel=Kp,
         ),
         grid=(nblocks, Kp // block_t),
-        in_specs=[pl.BlockSpec((block_b,), lambda b, t: (b,))],
-        out_specs=pl.BlockSpec((1, block_t), lambda b, t: (b, t)),
-        out_shape=jax.ShapeDtypeStruct((nblocks, Kp), jnp.int32),
-        interpret=interpret,
-    )(keys_p)
+        in_specs=[pl.BlockSpec((rows, LANES), lambda b, t: (b, 0))],
+        out_specs=pl.BlockSpec((None, block_t, 1), lambda b, t: (b, t, 0)),
+        out_shape=jax.ShapeDtypeStruct((nblocks, Kp, 1), jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(keys_p.reshape(Lp // LANES, LANES))
+    return hist.reshape(nblocks, Kp)
 
 
 @functools.partial(
@@ -175,14 +204,11 @@ def digit_placement(
     first ``len(keys)`` positions are meaningful; padding placements are
     sliced off by the caller.
     """
-    interpret = INTERPRET if interpret is None else interpret
     L = keys.shape[0]
-    block_t = _tile_width(nbins, block_t)
-    block_b = _block_rows(block_b, block_t)  # same clamp as the hist
-    Lp = round_up(max(L, block_b), block_b)
-    Kp = round_up(max(nbins, block_t), block_t)
+    block_b, block_t, Lp, Kp = _layout(L, nbins, block_b, block_t)
     keys_p = jnp.pad(keys, (0, Lp - L), constant_values=-1)
     nblocks = Lp // block_b
+    rows = block_b // LANES
     offs_p = jnp.pad(
         offsets.astype(jnp.int32),
         ((0, nblocks - offsets.shape[0]), (0, Kp - offsets.shape[1])),
@@ -194,11 +220,11 @@ def digit_placement(
         ),
         grid=(nblocks, Kp // block_t),
         in_specs=[
-            pl.BlockSpec((block_b,), lambda b, t: (b,)),
-            pl.BlockSpec((1, block_t), lambda b, t: (b, t)),
+            pl.BlockSpec((rows, LANES), lambda b, t: (b, 0)),
+            pl.BlockSpec((None, block_t, 1), lambda b, t: (b, t, 0)),
         ],
-        out_specs=pl.BlockSpec((block_b,), lambda b, t: (b,)),
-        out_shape=jax.ShapeDtypeStruct((Lp,), jnp.int32),
-        interpret=interpret,
-    )(keys_p, offs_p)
-    return pos[:L]
+        out_specs=pl.BlockSpec((rows, LANES), lambda b, t: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((Lp // LANES, LANES), jnp.int32),
+        interpret=resolve_interpret(interpret),
+    )(keys_p.reshape(Lp // LANES, LANES), offs_p.reshape(nblocks, Kp, 1))
+    return pos.reshape(Lp)[:L]

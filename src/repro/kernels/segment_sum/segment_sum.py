@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import INTERPRET, LANES, round_up
+from ..common import LANES, resolve_interpret, round_up
 
 
 def _cumsum_kernel(x_ref, out_ref, carry_ref):
@@ -66,7 +66,7 @@ def blocked_cumsum(
     x: jax.Array, *, block_b: int = 4096, interpret: bool | None = None
 ) -> jax.Array:
     """Inclusive prefix sum via sequential-grid carry scan."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     L = x.shape[0]
     Lp = round_up(max(L, block_b), block_b)
     xp = jnp.pad(x, (0, Lp - L))
@@ -149,7 +149,7 @@ def gather_masked_segscan(
     traffic over L is one read of ``vals``/``perm``/``slot``/``first``
     and one write of the scan.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     L = perm.shape[0]
     block_b = min(block_b, round_up(max(L, 1), 4096))
     Lp = round_up(max(L, block_b), block_b)
@@ -227,7 +227,7 @@ def gather2_masked_cumsum(
     against ``ops.FUSED_RESIDENT_MAX_BYTES`` together).  ``vals_a`` and
     ``vals_b`` must share a dtype (the caller resolves the promotion).
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     L = sa.shape[0]
     block_b = min(block_b, round_up(max(L, 1), 4096))
     Lp = round_up(max(L, block_b), block_b)
@@ -283,7 +283,7 @@ def gather_masked_cumsum(
     mode — fewer, bigger steps keep that overhead sublinear; short
     streams clamp down so they never pad up to a full block.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     L = perm.shape[0]
     block_b = min(block_b, round_up(max(L, 1), 4096))
     Lp = round_up(max(L, block_b), block_b)

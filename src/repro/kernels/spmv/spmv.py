@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import INTERPRET, round_up
+from ..common import resolve_interpret, round_up
 
 
 def _spmv_ell_kernel(cols_ref, vals_ref, x_ref, y_ref):
@@ -38,7 +38,7 @@ def spmv_ell(
     interpret: bool | None = None,
 ) -> jax.Array:
     """y[r] = sum_k vals[r, k] * x[cols[r, k]] with col == len(x) padding."""
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     M, K = cols.shape
     N = x.shape[0]
     Mp = round_up(max(M, block_r), block_r)

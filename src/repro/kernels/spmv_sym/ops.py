@@ -1,11 +1,12 @@
 """Dispatchers for the symmetric / blocked SpMV kernel family.
 
-Backend policy (mirrors ``kernels/segment_sum``): on a real TPU the
-Pallas kernels run compiled with the dense vector VMEM-resident,
-guarded by the shared 8 MB residency cap; off-TPU the jnp oracles in
-:mod:`.ref` run directly — they are the fast path there, and
-interpret-mode Pallas would only add overhead.  ``interpret=True``
-forces the kernels through the interpreter for cross-validation tests.
+Backend policy: ``interpret=None`` follows the ``spmv_sym`` tuning
+policy's ``method`` knob.  Its prior is ``"ref"`` on every backend —
+the jnp oracles in :mod:`.ref` — because the Pallas kernels gather
+from a 1-D VMEM-resident vector, which the TPU compiler does not lower
+("Only 2D gather is supported").  ``method="pallas"`` (or an explicit
+``interpret=True``/``False``) runs the kernels, guarded by the shared
+8 MB residency cap; the interpreter cross-validates them in tests.
 """
 from __future__ import annotations
 
@@ -16,7 +17,6 @@ import jax.numpy as jnp
 
 from ...core.csc import slot_columns
 from ...sparse import tuning
-from ..common import INTERPRET
 from .ref import spmv_bsr_ref, spmv_sym_ref
 from .spmv_sym import bsr_tiles, sym_streams
 
@@ -34,12 +34,12 @@ def _budget(M: int, dtype) -> int:
     return int(pol["resident_max_bytes"])
 
 
-def _use_kernel(resident_bytes: int, budget: int,
+def _use_kernel(resident_bytes: int, budget: int, method: str,
                 interpret: bool | None) -> bool:
     if resident_bytes > budget:
         return False
     if interpret is None:
-        return not INTERPRET          # compiled kernel only on real TPU
+        return method == "pallas"     # the policy decides
     return True                       # explicit True/False: run Pallas
 
 
@@ -101,8 +101,8 @@ def spmv_sym(diag, data, indices, indptr, x, *, block_b: int | None = None,
     if block_b is None:
         block_b = int(pol["block_b"])
     budget = _budget(M, x.dtype)
-    if M == 0 or nzmax == 0 or not _use_kernel(x.nbytes, budget,
-                                               interpret):
+    if M == 0 or nzmax == 0 or not _use_kernel(
+            x.nbytes, budget, pol["method"], interpret):
         return spmv_sym_ref(diag, data, indices, indptr, x)
     cols = jnp.clip(slot_columns(indptr, nzmax), 0, M - 1)
     up, cs = sym_streams(indices, cols, data, x, M=M, block_b=block_b,
@@ -126,8 +126,8 @@ def spmv_bsr(data, indices, indptr, x, *, shape, block: int,
     if block_t is None:
         block_t = int(pol["block_t"])
     resident = (N // b) * b * x.dtype.itemsize if b else 0
-    if M == 0 or nbmax == 0 or b == 0 \
-            or not _use_kernel(resident, _budget(N, x.dtype), interpret):
+    if M == 0 or nbmax == 0 or b == 0 or not _use_kernel(
+            resident, _budget(N, x.dtype), pol["method"], interpret):
         return spmv_bsr_ref(data, indices, indptr, x, shape=shape,
                             block=block)
     Mb, Nb = M // b, N // b

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..common import INTERPRET, LANES, round_up
+from ..common import LANES, resolve_interpret, round_up
 
 
 def _sym_streams_kernel(rows_ref, cols_ref, data_ref, x_ref,
@@ -59,7 +59,7 @@ def sym_streams(rows, cols, data, x, *, M: int, block_b: int = 65536,
     ``indptr`` boundaries).  ``rows`` carries ``M`` sentinels for
     padding; ``cols`` must be pre-clipped to ``[0, M)``.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     L = rows.shape[0]
     block_b = min(block_b, round_up(max(L, 1), 4096))
     Lp = round_up(max(L, block_b), block_b)
@@ -109,7 +109,7 @@ def bsr_tiles(brows, bcols, data, xr, *, Mb: int, block_t: int = 4096,
     resident; the caller scatter-adds the returned ``[nbmax, b]``
     partials into block rows.  ``bcols`` must be pre-clipped.
     """
-    interpret = INTERPRET if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     nb, b = data.shape[0], data.shape[1]
     Nb = xr.shape[0]
     block_t = min(block_t, round_up(max(nb, 1), 512))

@@ -1,10 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (
-    "--xla_force_host_platform_device_count=512 "
-    + os.environ.get("XLA_FLAGS", "")
-)
-# ^ MUST be the first lines: jax locks the device count on first init.
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this:
@@ -22,6 +15,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import time
 import traceback
@@ -281,7 +275,14 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str | None):
     return result
 
 
+#: placeholder host devices the production meshes are laid out on
+HOST_DEVICES = 512
+
+
 def main():
+    # the CPU device count is fixed when the backend starts; importing
+    # repro starts none, so setting it here (not at import) is in time
+    jax.config.update("jax_num_cpu_devices", HOST_DEVICES)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

@@ -2,8 +2,8 @@
 
 ``make_production_mesh`` is a FUNCTION (not a module constant) so that
 importing this module never touches jax device state.  The dry-run
-(launch/dryrun.py) sets ``XLA_FLAGS=--xla_force_host_platform_device_count=512``
-*before* any jax import to obtain placeholder devices.
+(``launch/dryrun.py``) asks for 512 placeholder host devices in its
+``main()``, before any backend starts.
 
 Axes:
   single-pod : (16, 16)      -> ("data", "model")       = 256 chips
@@ -19,24 +19,13 @@ from __future__ import annotations
 import functools
 
 import jax
-
-try:  # jax >= 0.5 explicit-sharding API; absent on 0.4.x
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - version-dependent
-    AxisType = None
-
-
-def _axis_kwargs(n_axes: int) -> dict:
-    """``axis_types=`` kwarg when the running jax supports it, else {}."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 @functools.lru_cache(maxsize=None)
@@ -51,7 +40,7 @@ def make_data_mesh(n: int | None = None, *, axis: str = "data"):
     default mesh on every call.
     """
     n = len(jax.devices()) if n is None else n
-    return jax.make_mesh((n,), (axis,), **_axis_kwargs(1))
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1):
@@ -59,7 +48,8 @@ def make_host_mesh(*, data: int | None = None, model: int = 1):
     n = len(jax.devices())
     if data is None:
         data = n // model
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_kwargs(2))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh) -> tuple[str, ...]:

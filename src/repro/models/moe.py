@@ -162,9 +162,8 @@ def moe_ffn_shardmap(params, x, cfg, mesh, dp_axes):
     ``model``) crosses shards.  This removes GSPMD's replicated
     dispatch buffers observed in the probe HLO.
     """
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-
-    from ..core.compat import shard_map
 
     B, S, D = x.shape
     E, K = cfg.moe.n_experts, cfg.moe.top_k
@@ -193,7 +192,7 @@ def moe_ffn_shardmap(params, x, cfg, mesh, dp_axes):
 
     spec_x = P(dp_axes, None, None)
     dispatch = shard_map(
-        _dispatch, mesh=mesh,
+        _dispatch, mesh=mesh, check_vma=False,
         in_specs=(P(None, None), spec_x),
         out_specs=(P(dp_axes, None, None, None), P(dp_axes, None),
                    P(dp_axes, None, None), P(dp_axes, None),
@@ -220,7 +219,7 @@ def moe_ffn_shardmap(params, x, cfg, mesh, dp_axes):
         return y.reshape(1, B // dp, S, D).astype(out_blk.dtype)
 
     combine = shard_map(
-        _combine, mesh=mesh,
+        _combine, mesh=mesh, check_vma=False,
         in_specs=(P(dp_axes, None, None, None), P(dp_axes, None),
                   P(dp_axes, None, None)),
         out_specs=P(dp_axes, None, None, None),

@@ -56,7 +56,7 @@ _SUM_PRIMITIVES = frozenset(
         "scatter-add",
     }
 )
-_HOST_PRIMITIVES = frozenset({"infeed", "outfeed"})
+_HOST_PRIMITIVES = frozenset({"infeed", "outfeed", "debug_print"})
 _16BIT_FLOATS = ("bfloat16", "float16")
 
 

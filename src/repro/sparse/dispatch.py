@@ -32,7 +32,8 @@ Pallas kernels would run in interpret mode and the XLA sort wins).
 The *merge* backends (``SparsePattern.update``'s delta merge-by-key —
 ``repro.kernels.merge``) follow the same pattern with their own
 registry: :func:`register_merge_method` / :func:`merge_search` /
-:func:`default_merge_method` (``"pallas"`` on TPU, ``"jnp"`` off-TPU).
+:func:`default_merge_method` (``"jnp"`` on every backend: the Pallas
+search does not compile for the TPU).
 """
 from __future__ import annotations
 
@@ -207,15 +208,6 @@ register_method("radix", _perm_radix)
 # Merge backends (SparsePattern.update's sorted-stream merge-by-key)
 # ---------------------------------------------------------------------------
 _MERGE_METHODS: Dict[str, PermFn] = {}
-
-#: merge backend ``merge_method=None`` resolves to on TPU (prior pin,
-#: owned by the ``merge`` tuning spec).
-DEFAULT_MERGE_TPU = tuning.prior_value("merge", "method", backend="tpu")
-#: off-TPU merge default: the Pallas search would run in interpret
-#: mode, so the pure-jnp binary search wins (bit-identical by contract).
-DEFAULT_MERGE_INTERPRET = tuning.prior_value(
-    "merge", "method", backend="cpu"
-)
 
 
 def register_merge_method(name: str, fn: PermFn) -> None:

@@ -24,9 +24,10 @@ module is that layer, sitting between the plan/fill core and callers:
 * **Persistent warm restarts**: plan/product cache entries are written
   through to ``cache_dir`` (one pickle of the exact cache key + the
   host-side plan pytree per entry) and loaded back on construction, so
-  a restarted server re-plans **nothing**; the JAX persistent
-  compilation cache is pointed at the same directory, so on backends
-  that support it the XLA executables are disk-cached too.
+  a restarted server re-plans **nothing**; JAX's persistent
+  compilation cache is turned on too (``JAX_COMPILATION_CACHE_DIR``
+  where set, else a fixed ``.jax_cache`` in the checkout), so on
+  backends that support it the XLA executables are disk-cached as well.
 * **Request batching**: :meth:`PlanService.assemble_many` groups
   same-structure requests from independent streams and rides one
   ``vmap``-batched fill executable across the group.
@@ -38,7 +39,7 @@ executable additionally freezes the primal computation only — take
 gradients through ``pattern.assemble``/``ops`` (the jit path), not
 through a compiled executable.
 
-    >>> import numpy as np, tempfile
+    >>> import numpy as np, os, tempfile
     >>> from repro.sparse.serving import PlanService
     >>> from repro.sparse import plan_cache_clear
     >>> plan_cache_clear()
@@ -56,6 +57,12 @@ through a compiled executable.
     >>> svc2.stats()["plan"]["misses"]        # no re-planning
     0
     >>> bool(np.array_equal(np.asarray(S3.data), np.asarray(S.data)))
+    True
+    >>> import jax                            # the compile cache: env var
+    >>> from repro.sparse.serving import DEFAULT_COMPILATION_CACHE_DIR
+    >>> jax.config.jax_compilation_cache_dir == (
+    ...     os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    ...     or str(DEFAULT_COMPILATION_CACHE_DIR))
     True
 """
 from __future__ import annotations
@@ -179,27 +186,29 @@ def tcmalloc_hint() -> str | None:
     return None
 
 
-def enable_compilation_cache(path) -> bool:
-    """Point JAX's persistent compilation cache at ``path``.
+#: the compile-cache directory used when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: fixed inside the checkout, so every process of every run
+#: (the cache key includes the path) finds what an earlier one wrote.
+DEFAULT_COMPILATION_CACHE_DIR = (
+    Path(__file__).resolve().parents[3] / ".jax_cache"
+)
 
-    Best-effort: flag names vary across jax versions and some backends
-    do not persist executables — plan persistence (the bigger win: the
-    symbolic phase dominates) never depends on this.  Returns whether
-    the cache directory was accepted.
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it
+    and no directory is set here; otherwise the cache goes to
+    :data:`DEFAULT_COMPILATION_CACHE_DIR`.  Every executable is cached
+    (no minimum compile time or entry size).  Plan persistence never
+    depends on this — it lives in ``PlanService(cache_dir=...)``.
     """
-    try:
-        jax.config.update("jax_compilation_cache_dir", str(path))
-    except Exception:  # noqa: BLE001 - flag absent on this jax
-        return False
-    for flag, val in (
-        ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ("jax_persistent_cache_min_entry_size_bytes", -1),
-    ):
-        try:
-            jax.config.update(flag, val)
-        except Exception:  # noqa: BLE001
-            pass
-    return True
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(DEFAULT_COMPILATION_CACHE_DIR))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------
@@ -329,9 +338,10 @@ class PlanService:
     cache_dir:
         Optional persistence root.  When set, plan/product entries are
         written through on first use, loaded back on construction
-        (``loaded_plans``/``loaded_products`` report how many), and the
-        JAX persistent compilation cache is pointed at
-        ``cache_dir/xla``.
+        (``loaded_plans``/``loaded_products`` report how many), and
+        JAX's persistent compilation cache is turned on
+        (:func:`enable_compilation_cache`: ``JAX_COMPILATION_CACHE_DIR``
+        where set, else the checkout's fixed ``.jax_cache``).
     exec_capacity:
         Executable-tier LRU capacity (env override:
         ``REPRO_EXEC_CACHE_SIZE``).
@@ -362,7 +372,7 @@ class PlanService:
         if cache_dir is not None:
             self.cache_dir = Path(cache_dir)
             self.cache_dir.mkdir(parents=True, exist_ok=True)
-            enable_compilation_cache(self.cache_dir / "xla")
+            enable_compilation_cache()
             self.loaded_plans, self.loaded_products = load_caches(
                 self.cache_dir
             )

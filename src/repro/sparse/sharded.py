@@ -50,9 +50,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..core.compat import shard_map
 from ..core.coo import COO
 from ..core.csc import CSC, slot_columns
 from ..core.csc import spmv as csc_spmv
@@ -185,7 +185,7 @@ def _sharded_spmv(data, indices, indptr, nnz, x, *, mesh, axis, shape):
 
     y = shard_map(
         _local,
-        mesh=mesh,
+        mesh=mesh, check_vma=False,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P()),
         out_specs=P(axis),
     )(data, indices, indptr, nnz, x)
@@ -394,7 +394,7 @@ def _plan_sharded_jit(rows, cols, *, shape, mesh, axis, capacity, nzb,
 
     return shard_map(
         _local,
-        mesh=mesh,
+        mesh=mesh, check_vma=False,
         in_specs=(P(axis), P(axis)),
         out_specs=tuple([P(axis)] * 9),
     )(rows, cols)
@@ -529,7 +529,7 @@ def _route_fill(mesh, axis, capacity, nzb, send_slot, perm, slot, vals):
 
     return shard_map(
         _local,
-        mesh=mesh,
+        mesh=mesh, check_vma=False,
         in_specs=(P(axis), P(axis), P(axis), P(None, axis)),
         out_specs=P(axis),
     )(send_slot, perm, slot, vals)
@@ -568,7 +568,7 @@ def _route_fill_bwd(mesh, axis, capacity, nzb, res, g):
 
     g_vals = shard_map(
         _local,
-        mesh=mesh,
+        mesh=mesh, check_vma=False,
         in_specs=(P(axis), P(axis), P(axis), P(axis)),
         out_specs=P(None, axis),
     )(send_slot, perm, slot, g)

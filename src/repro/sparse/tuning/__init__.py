@@ -529,11 +529,9 @@ register_kernel_spec(
     KernelSpec(
         "merge",
         (
-            Knob(
-                "method",
-                {"tpu": "pallas", "*": "jnp"},
-                candidates=("jnp", "pallas"),
-            ),
+            # "jnp" everywhere: the Pallas search gathers from 1-D
+            # VMEM-resident keys, which the TPU compiler does not lower
+            Knob("method", "jnp", candidates=("jnp", "pallas")),
             Knob("block_b", 65536, candidates=(32768, 65536, 131072)),
             Knob("resident_max_bytes", RESIDENT_BUDGET_BYTES),
         ),
@@ -579,6 +577,9 @@ register_kernel_spec(
     KernelSpec(
         "spmv_sym",
         (
+            # "ref" everywhere: the Pallas kernels gather from a 1-D
+            # VMEM-resident vector, which the TPU compiler does not lower
+            Knob("method", "ref", candidates=("ref", "pallas")),
             Knob("block_b", 65536, candidates=(32768, 65536, 131072)),
             Knob("block_t", 4096, candidates=(2048, 4096, 8192)),
             Knob("resident_max_bytes", RESIDENT_BUDGET_BYTES),

@@ -76,3 +76,29 @@ def test_gate_against_synthetic_base_json(tmp_path, capsys):
                             tolerance=0.10)
     assert failures == []
     assert COMPARE_EPS_US > 0  # the floor is a real, documented constant
+
+
+# ---------------------------------------------------------------------------
+# Bandwidth roofs of --roofline
+# ---------------------------------------------------------------------------
+class _Device:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def test_roofline_peaks_keyed_by_device_kind(monkeypatch):
+    from benchmarks import roofline
+
+    rows = [{"name": "k", "bandwidth_gbs": 409.5}, {"name": "no_bw"}]
+    v5e = _Device("tpu", "TPU v5 lite")
+    assert roofline.annotate_roofline(rows, v5e) == 1
+    assert rows[0]["peak_gbs"] == 819.0 and rows[0]["roofline_frac"] == 0.5
+    # a TPU the table does not know has no borrowed peak
+    with pytest.raises(KeyError, match="TPU v9"):
+        roofline.bandwidth_roof(_Device("tpu", "TPU v9"))
+    # the CPU's roof is its measured STREAM, never named a device peak
+    monkeypatch.setattr(roofline, "_CPU_STREAM_GBS", [10.0])
+    rows = [{"name": "k", "bandwidth_gbs": 5.0}]
+    roofline.annotate_roofline(rows, _Device("cpu", "cpu"))
+    assert rows[0]["cpu_stream_gbs"] == 10.0
+    assert rows[0]["cpu_stream_frac"] == 0.5 and "peak_gbs" not in rows[0]

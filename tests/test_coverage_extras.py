@@ -73,9 +73,8 @@ def test_nzmax_overflow_is_padded_not_corrupt():
 
 
 def _child_env():
-    """Child env for launcher tests: importing repro.launch.dryrun inside
-    the pytest process sets XLA_FLAGS=...512 (its documented first-lines
-    contract); children must NOT inherit it."""
+    """Child env for launcher tests: no XLA_FLAGS of the pytest
+    process (such as a forced host-device count) reaches the child."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")

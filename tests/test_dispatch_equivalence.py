@@ -151,14 +151,14 @@ def test_fused_overflow_warns_once_without_x64():
 
 
 def test_fused_uses_int64_key_under_x64():
-    from jax.experimental import enable_x64
+    import jax
 
     rows = np.array([0, 5, 3, 5], np.int32)
     cols = np.array([1, 0, 2, 0], np.int32)
     M = N = 46341
     dispatch._reset_fused_fallback_warning()
     import warnings as _w
-    with enable_x64(), _w.catch_warnings():
+    with jax.enable_x64(), _w.catch_warnings():
         _w.simplefilter("error", RuntimeWarning)  # no fallback warning
         p = dispatch.sorted_permutation(rows, cols, M=M, N=N,
                                         method="fused")

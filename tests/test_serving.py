@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import pickle
 import threading
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -521,7 +522,8 @@ def test_service_update_retires_spgemm_executables_and_products():
     _assert_same_csc(C2, ops.matmul(A0, B))
 
 
-def test_service_update_retires_persisted_entries(tmp_path):
+def test_service_update_retires_persisted_entries(
+        tmp_path, private_compile_cache):
     n, cap = 32, 216
     ii, jj, ss = _triplet(n, 200, seed=39)
     ai, aj, av = _delta(n, 16, seed=40)
@@ -547,7 +549,8 @@ def test_service_update_retires_persisted_entries(tmp_path):
 # ---------------------------------------------------------------------------
 # Persistence + warm restart
 # ---------------------------------------------------------------------------
-def test_persistence_roundtrip_and_warm_restart(tmp_path):
+def test_persistence_roundtrip_and_warm_restart(
+        tmp_path, private_compile_cache):
     n = 48
     ii, jj, ss = _triplet(n, 300, seed=8)
     kk, ll, tt = _triplet(n, 300, seed=9)
@@ -587,7 +590,8 @@ def test_save_caches_flushes_existing_entries(tmp_path):
     assert plan_cache_info()["misses"] == 0
 
 
-def test_corrupt_cache_entry_degrades_to_replan(tmp_path):
+def test_corrupt_cache_entry_degrades_to_replan(
+        tmp_path, private_compile_cache):
     n = 32
     ii, jj, ss = _triplet(n, 200, seed=12)
     svc = PlanService(cache_dir=tmp_path)
@@ -643,6 +647,22 @@ def test_serve_namespace_reexports_serving_api():
                  "tcmalloc_hint", "prefill", "decode_step", "init_cache"):
         assert hasattr(serve, name), name
         assert name in serve.__all__
+
+
+@pytest.mark.parametrize("env_set", [True, False])
+def test_compile_cache_dir_follows_env_else_checkout(
+        env_set, tmp_path, monkeypatch, private_compile_cache):
+    checkout_cache = Path(__file__).resolve().parents[1] / ".jax_cache"
+    if env_set:
+        # the variable is JAX's to read: the service sets no directory
+        PlanService(cache_dir=tmp_path / "plans")
+        assert jax.config.jax_compilation_cache_dir == private_compile_cache
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        PlanService(cache_dir=tmp_path / "plans")
+        assert jax.config.jax_compilation_cache_dir == str(checkout_cache)
+    # plan pickles stay in cache_dir; no compile cache is put there
+    assert not (tmp_path / "plans" / "xla").exists()
 
 
 def test_cache_info_keeps_historical_keys():

@@ -239,11 +239,8 @@ def test_convert_to_sharded_roundtrip_single_device():
 
     rows, cols, vals = _triplets(29, 400, 20, 24)
     S = plan(rows, cols, (20, 24)).assemble(jnp.asarray(vals))
-    # pin a 1-device mesh: the default spans ALL devices, and under the
-    # full suite the process sees 512 fake host devices (importing
-    # repro.launch.dryrun — e.g. via tests/test_sharding.py — sets
-    # XLA_FLAGS=--xla_force_host_platform_device_count=512 at import
-    # time), which would compile a 512-way shard_map here
+    # pin a 1-device mesh: the default spans ALL devices, whatever
+    # host-device count the process was started with
     Sh = convert(S, "sharded", mesh=make_data_mesh(1))
     assert isinstance(Sh, ShardedCSC) and format_of(Sh) == "sharded"
     np.testing.assert_allclose(np.asarray(Sh.to_dense()),

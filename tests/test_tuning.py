@@ -56,8 +56,11 @@ def test_unknown_family_and_knob_raise():
 def test_priors_are_backend_aware():
     assert tuning.prior_policy("plan", "tpu")["method"] == "radix"
     assert tuning.prior_policy("plan", "cpu")["method"] == "fused"
-    assert tuning.prior_value("merge", "method", "tpu") == "pallas"
-    assert tuning.prior_value("merge", "method", "cpu") == "jnp"
+    # the Pallas merge and spmv_sym kernels do not compile for the TPU,
+    # so their priors name the XLA path on every backend
+    for backend in ("tpu", "cpu"):
+        assert tuning.prior_value("merge", "method", backend) == "jnp"
+        assert tuning.prior_value("spmv_sym", "method", backend) == "ref"
 
 
 def test_every_resident_budget_prior_is_the_registry_budget():
@@ -265,7 +268,8 @@ def test_resolved_policy_bit_identical_to_explicit_priors():
     )
 
 
-def test_serving_persists_table_and_reports_fingerprint(tmp_path):
+def test_serving_persists_table_and_reports_fingerprint(
+        tmp_path, private_compile_cache):
     svc = serving.PlanService(cache_dir=tmp_path)
     stats = svc.stats()
     assert stats["tuning_fingerprint"] == "prior"
