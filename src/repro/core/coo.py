@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .spans import UPLOAD, VALIDATE, span
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -62,31 +64,35 @@ def coo_from_matlab(ii, jj, ss, shape=None) -> COO:
     validated (integral, >= 1), converted to int32 and the matrix
     dimensions are inferred as the max index when ``shape`` is omitted.
     """
-    ii = np.asarray(ii)
-    jj = np.asarray(jj)
-    ss = np.asarray(ss, dtype=np.float64)
-    if ii.shape != jj.shape or ii.shape != ss.shape:
-        raise ValueError("i, j, s must have identical shapes")
-    if ii.size and (np.any(ii < 1) or np.any(ii != np.floor(ii))):
-        raise ValueError("bad row index (must be positive integers)")
-    if jj.size and (np.any(jj < 1) or np.any(jj != np.floor(jj))):
-        raise ValueError("bad column index (must be positive integers)")
-    ii = ii.astype(np.int32).ravel()
-    jj = jj.astype(np.int32).ravel()
-    ss = ss.ravel()
-    if shape is None:
-        M = int(ii.max()) if ii.size else 0
-        N = int(jj.max()) if jj.size else 0
-    else:
-        M, N = int(shape[0]), int(shape[1])
-        if ii.size and (ii.max() > M or jj.max() > N):
-            raise ValueError("index exceeds matrix dimensions")
-    return COO(
-        rows=jnp.asarray(ii - 1),
-        cols=jnp.asarray(jj - 1),
-        vals=jnp.asarray(ss.astype(np.float32)),
-        shape=(M, N),
-    )
+    with span(VALIDATE):
+        ii = np.asarray(ii)
+        jj = np.asarray(jj)
+        ss = np.asarray(ss, dtype=np.float64)
+        if ii.shape != jj.shape or ii.shape != ss.shape:
+            raise ValueError("i, j, s must have identical shapes")
+        if ii.size and (np.any(ii < 1) or np.any(ii != np.floor(ii))):
+            raise ValueError("bad row index (must be positive integers)")
+        if jj.size and (np.any(jj < 1) or np.any(jj != np.floor(jj))):
+            raise ValueError("bad column index (must be positive integers)")
+        ii = ii.astype(np.int32).ravel()
+        jj = jj.astype(np.int32).ravel()
+        ss = ss.ravel()
+        if shape is None:
+            M = int(ii.max()) if ii.size else 0
+            N = int(jj.max()) if jj.size else 0
+        else:
+            M, N = int(shape[0]), int(shape[1])
+            if ii.size and (ii.max() > M or jj.max() > N):
+                raise ValueError("index exceeds matrix dimensions")
+    # each copy is issued as soon as its host array is made, so making
+    # the next one overlaps it
+    with span(UPLOAD, bytes=ii.nbytes + jj.nbytes + 4 * ss.size):
+        return COO(
+            rows=jnp.asarray(ii - 1),
+            cols=jnp.asarray(jj - 1),
+            vals=jnp.asarray(ss.astype(np.float32)),
+            shape=(M, N),
+        )
 
 
 @partial(jax.jit, static_argnames=("M", "N"))

@@ -24,6 +24,8 @@ import numpy as np
 
 from ..core.coo import COO, coo_from_matlab
 from ..core.csc import CSC, slot_columns
+from ..core.spans import (EXPAND, FSPARSE, PLAN, PLAN_CACHE, PLAN_KEY,
+                          FILL, span)
 from .dispatch import resolve_method
 from .lru import LRUCache
 from .pattern import (SparsePattern, plan_coo, plan_symmetric,
@@ -41,41 +43,42 @@ def expand_indices(ii, jj, ss):
     raises the Matlab-compatible errors instead of silently expanding
     or crashing inside ``reshape``.
     """
-    ii = np.asarray(ii, dtype=np.float64)
-    jj = np.asarray(jj, dtype=np.float64)
-    ss = np.asarray(ss, dtype=np.float64)
-    if ii.ndim <= 1 and jj.ndim <= 1:
-        if ii.size == jj.size:
-            if ss.size == 1:
-                ss = np.full(ii.shape, float(ss.ravel()[0]))
-            elif ss.size != ii.size:
+    with span(EXPAND):
+        ii = np.asarray(ii, dtype=np.float64)
+        jj = np.asarray(jj, dtype=np.float64)
+        ss = np.asarray(ss, dtype=np.float64)
+        if ii.ndim <= 1 and jj.ndim <= 1:
+            if ii.size == jj.size:
+                if ss.size == 1:
+                    ss = np.full(ii.shape, float(ss.ravel()[0]))
+                elif ss.size != ii.size:
+                    raise ValueError("vectors must be the same length")
+                return ii.ravel(), jj.ravel(), ss.ravel()
+            if ii.size != 1 and jj.size != 1:
+                # mismatched 1-d vectors are an error in Matlab, not an
+                # implicit outer product (only scalars broadcast)
                 raise ValueError("vectors must be the same length")
-            return ii.ravel(), jj.ravel(), ss.ravel()
-        if ii.size != 1 and jj.size != 1:
-            # mismatched 1-d vectors are an error in Matlab, not an
-            # implicit outer product (only scalars broadcast)
-            raise ValueError("vectors must be the same length")
-    # outer-product expansion: i column (ni, 1), j row (1, nj) -> (ni, nj)
-    ii2 = ii.reshape(-1, 1)
-    jj2 = jj.reshape(1, -1)
-    ni, nj = ii2.shape[0], jj2.shape[1]
-    grid_i = np.broadcast_to(ii2, (ni, nj))
-    grid_j = np.broadcast_to(jj2, (ni, nj))
-    if ss.size == 1:
-        grid_s = np.full((ni, nj), float(ss.ravel()[0]))
-    elif ss.shape == (ni, nj):
-        grid_s = ss
-    elif ss.ndim == 1 and ss.size == ni * nj:
-        grid_s = ss.reshape(ni, nj)
-    elif ss.ndim == 2 and ss.shape in ((ni, 1), (1, nj)):
-        grid_s = np.broadcast_to(ss, (ni, nj))
-    else:
-        raise ValueError(
-            f"cannot expand s of shape {ss.shape} over a ({ni}, {nj}) "
-            f"index grid; expected a scalar, ({ni}, {nj}), ({ni}, 1), "
-            f"(1, {nj}), or a flat vector of {ni * nj} values"
-        )
-    return grid_i.ravel(), grid_j.ravel(), grid_s.ravel()
+        # outer-product expansion: i column (ni, 1), j row (1, nj) -> (ni, nj)
+        ii2 = ii.reshape(-1, 1)
+        jj2 = jj.reshape(1, -1)
+        ni, nj = ii2.shape[0], jj2.shape[1]
+        grid_i = np.broadcast_to(ii2, (ni, nj))
+        grid_j = np.broadcast_to(jj2, (ni, nj))
+        if ss.size == 1:
+            grid_s = np.full((ni, nj), float(ss.ravel()[0]))
+        elif ss.shape == (ni, nj):
+            grid_s = ss
+        elif ss.ndim == 1 and ss.size == ni * nj:
+            grid_s = ss.reshape(ni, nj)
+        elif ss.ndim == 2 and ss.shape in ((ni, 1), (1, nj)):
+            grid_s = np.broadcast_to(ss, (ni, nj))
+        else:
+            raise ValueError(
+                f"cannot expand s of shape {ss.shape} over a ({ni}, {nj}) "
+                f"index grid; expected a scalar, ({ni}, {nj}), ({ni}, 1), "
+                f"(1, {nj}), or a flat vector of {ni * nj} values"
+            )
+        return grid_i.ravel(), grid_j.ravel(), grid_s.ravel()
 
 
 def fsparse(ii, jj, ss, shape=None, nzmax: int | None = None,
@@ -120,30 +123,36 @@ def fsparse(ii, jj, ss, shape=None, nzmax: int | None = None,
     tiles.  Both compose with ``method=`` planning backends; neither
     supports ``method="sharded"`` (clear error).
     """
-    method = method if method == "sharded" else resolve_method(method)
-    validate_accum(accum)
-    _validate_format(format, block)
-    ii, jj, ss = expand_indices(ii, jj, ss)
-    coo = coo_from_matlab(ii, jj, ss, shape=shape)
-    if method == "sharded":
-        _reject_sharded_format(format)
-        _reject_sharded_accum(accum)
-        _reject_sharded_slack(nzmax_slack)
-        pat = _plan_sharded_coo(coo, nzmax, mesh)
-        return pat.assemble(coo.vals)
-    _reject_unused_mesh(mesh, method)
-    if format == "symcsc":
-        spat = plan_symmetric(np.asarray(coo.rows), np.asarray(coo.cols),
-                              coo.shape, nzmax=nzmax, method=method,
-                              accum=accum)
-        return spat.assemble(coo.vals)
-    out = plan_coo(coo, nzmax=nzmax, method=method, accum=accum,
-                   nzmax_slack=nzmax_slack).assemble(coo.vals)
-    if format == "bsr":
-        from .formats import convert
+    with span(FSPARSE) as request:
+        method = method if method == "sharded" else resolve_method(method)
+        validate_accum(accum)
+        _validate_format(format, block)
+        ii, jj, ss = expand_indices(ii, jj, ss)
+        request.set_metadata(L=ii.size)
+        coo = coo_from_matlab(ii, jj, ss, shape=shape)
+        if method == "sharded":
+            _reject_sharded_format(format)
+            _reject_sharded_accum(accum)
+            _reject_sharded_slack(nzmax_slack)
+        else:
+            _reject_unused_mesh(mesh, method)
+        with span(PLAN):
+            if method == "sharded":
+                pat = _plan_sharded_coo(coo, nzmax, mesh)
+            elif format == "symcsc":
+                pat = plan_symmetric(np.asarray(coo.rows),
+                                     np.asarray(coo.cols), coo.shape,
+                                     nzmax=nzmax, method=method, accum=accum)
+            else:
+                pat = plan_coo(coo, nzmax=nzmax, method=method, accum=accum,
+                               nzmax_slack=nzmax_slack)
+        with span(FILL):
+            out = pat.assemble(coo.vals)
+        if format == "bsr":
+            from .formats import convert
 
-        return convert(out, "bsr", block=block)
-    return out
+            return convert(out, "bsr", block=block)
+        return out
 
 
 def _reject_unused_mesh(mesh, method):
@@ -290,20 +299,25 @@ def plan_lookup(ii, jj, ss, shape=None, nzmax: int | None = None,
     # part of the cache identity too; so are the target format and its
     # block size — a SymPattern and a SparsePattern over the same
     # triplets are different resident plans
-    key = _cache_key(np.asarray(coo.rows), np.asarray(coo.cols),
-                     coo.shape, nzmax, method,
-                     (accum, format, int(block)) + tuple(extra))
+    rows, cols = coo.rows, coo.cols
+    with span(PLAN_KEY, bytes=rows.nbytes + cols.nbytes):
+        key = _cache_key(np.asarray(rows), np.asarray(cols),
+                         coo.shape, nzmax, method,
+                         (accum, format, int(block)) + tuple(extra))
 
     def build():
-        if method == "sharded":
-            return _plan_sharded_coo(coo, nzmax, mesh)
-        if format == "symcsc":
-            return plan_symmetric(np.asarray(coo.rows),
-                                  np.asarray(coo.cols), coo.shape,
-                                  nzmax=nzmax, method=method, accum=accum)
-        return plan_coo(coo, nzmax=nzmax, method=method, accum=accum)
+        with span(PLAN):
+            if method == "sharded":
+                return _plan_sharded_coo(coo, nzmax, mesh)
+            if format == "symcsc":
+                return plan_symmetric(np.asarray(rows), np.asarray(cols),
+                                      coo.shape, nzmax=nzmax, method=method,
+                                      accum=accum)
+            return plan_coo(coo, nzmax=nzmax, method=method, accum=accum)
 
-    return key, _PLAN_CACHE.get_or_create(key, build), coo
+    with span(PLAN_CACHE):
+        pat = _PLAN_CACHE.get_or_create(key, build)
+    return key, pat, coo
 
 
 def sparse2(ii, jj, ss, shape=None, nzmax: int | None = None,

@@ -291,10 +291,12 @@ def matmul(A, x) -> "jax.Array | CSC":
         return _spgemm(A, x)
     x = jnp.asarray(x)
     fn, A = _dispatch("spmv", A, hub="csc")
-    if x.ndim == 1:
-        return fn(A, x)
-    if x.ndim == 2:
-        return jax.vmap(lambda col: fn(A, col), in_axes=1, out_axes=1)(x)
+    with jax.named_scope("spmv"):
+        if x.ndim == 1:
+            return fn(A, x)
+        if x.ndim == 2:
+            return jax.vmap(lambda col: fn(A, col), in_axes=1,
+                            out_axes=1)(x)
     raise ValueError(f"matmul expects a vector or matrix, got ndim={x.ndim}")
 
 

@@ -186,9 +186,10 @@ class SparsePattern:
                 "assemble_batch/vmap for batched fills"
             )
         dtype = fill_dtype(vals)
-        return _scatter_vjp(
-            self.nzmax, accum, self.perm, self.slot, vals.astype(dtype)
-        )
+        with jax.named_scope("fill"):
+            return _scatter_vjp(
+                self.nzmax, accum, self.perm, self.slot, vals.astype(dtype)
+            )
 
     def reduce_rows(self, mat: jax.Array, *, accum: str | None = None
                     ) -> jax.Array:
@@ -709,29 +710,33 @@ def _merge_sorted_streams(
     exists to avoid).  One jit end to end, feeding the shared Parts-3/4
     tail.
     """
-    nA, nB = sr_a.shape[0], add_rows.shape[0]
-    Lm = nA + nB
-    if nB == 0:
-        return pattern_from_sorted(sr_a, sc_a, pa, M=M, N=N, nzmax=nzmax)
-    dperm = sorted_permutation(add_rows, add_cols, M=M, N=N, method=method)
-    sr_b = add_rows[dperm]
-    sc_b = add_cols[dperm]
-    # delta elements land after every survivor in the concatenated
-    # input order: offset their perm values past the survivors
-    pb = dperm.astype(jnp.int32) + jnp.int32(L_keep)
-    off_b = merge_search(sr_b, sc_b, sr_a, sc_a, side="right",
-                         method=merge_method)
-    pos_b = jnp.arange(nB, dtype=jnp.int32) + off_b
-    occ = jnp.zeros((Lm,), jnp.int32).at[pos_b].set(1, mode="drop")
-    nb_upto = jnp.cumsum(occ).astype(jnp.int32)  # deltas at positions <= q
-    q = jnp.arange(Lm, dtype=jnp.int32)
-    is_b = occ == 1
-    # source index into concat([A, B]) for every merged position
-    g = jnp.where(is_b, nA + nb_upto - 1, q - nb_upto)
-    r_m = jnp.concatenate([sr_a, sr_b])[g]
-    c_m = jnp.concatenate([sc_a, sc_b])[g]
-    p_m = jnp.concatenate([pa, pb])[g]
-    return pattern_from_sorted(r_m, c_m, p_m, M=M, N=N, nzmax=nzmax)
+    with jax.named_scope("merge"):
+        nA, nB = sr_a.shape[0], add_rows.shape[0]
+        Lm = nA + nB
+        if nB == 0:
+            return pattern_from_sorted(sr_a, sc_a, pa, M=M, N=N,
+                                       nzmax=nzmax)
+        dperm = sorted_permutation(add_rows, add_cols, M=M, N=N,
+                                   method=method)
+        sr_b = add_rows[dperm]
+        sc_b = add_cols[dperm]
+        # delta elements land after every survivor in the concatenated
+        # input order: offset their perm values past the survivors
+        pb = dperm.astype(jnp.int32) + jnp.int32(L_keep)
+        off_b = merge_search(sr_b, sc_b, sr_a, sc_a, side="right",
+                             method=merge_method)
+        pos_b = jnp.arange(nB, dtype=jnp.int32) + off_b
+        occ = jnp.zeros((Lm,), jnp.int32).at[pos_b].set(1, mode="drop")
+        # deltas at positions <= q
+        nb_upto = jnp.cumsum(occ).astype(jnp.int32)
+        q = jnp.arange(Lm, dtype=jnp.int32)
+        is_b = occ == 1
+        # source index into concat([A, B]) for every merged position
+        g = jnp.where(is_b, nA + nb_upto - 1, q - nb_upto)
+        r_m = jnp.concatenate([sr_a, sr_b])[g]
+        c_m = jnp.concatenate([sc_a, sc_b])[g]
+        p_m = jnp.concatenate([pa, pb])[g]
+        return pattern_from_sorted(r_m, c_m, p_m, M=M, N=N, nzmax=nzmax)
 
 
 def trivial_pattern(
@@ -806,8 +811,10 @@ def plan(
         return trivial_pattern(L, (M, N), nzmax=nzmax, accum=accum)
     rows = rows.astype(jnp.int32)
     cols = cols.astype(jnp.int32)
-    perm = sorted_permutation(rows, cols, M=M, N=N, method=method)
-    pat = pattern_from_perm(rows, cols, perm, M=M, N=N, nzmax=nzmax)
+    with jax.named_scope("plan.sort"):
+        perm = sorted_permutation(rows, cols, M=M, N=N, method=method)
+    with jax.named_scope("plan.compress"):
+        pat = pattern_from_perm(rows, cols, perm, M=M, N=N, nzmax=nzmax)
     return pat if accum == "sum" else dataclasses.replace(pat, accum=accum)
 
 

@@ -69,6 +69,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
+import itertools
 import os
 import pickle
 import threading
@@ -80,6 +81,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.csc import CSC
+from ..core.spans import (ASSEMBLE, ASSEMBLE_MANY, COMPILE, EXEC_CACHE, FILL,
+                          MULTIPLY, SPMV, UPDATE_STRUCTURE, span)
 from . import tuning
 from .analysis.invariants import maybe_validate_pattern, validate_pattern
 from .errors import CacheCorruptionWarning, InvariantViolation
@@ -357,6 +360,7 @@ class PlanService:
     def __init__(self, *, cache_dir=None, exec_capacity: int = 64,
                  donate: bool | None = None, method: str | None = None):
         self.method = method
+        self._requests = itertools.count()  # the ``request`` span stat
         self.donate = (
             jax.default_backend() in ("gpu", "tpu")
             if donate is None else bool(donate)
@@ -451,9 +455,14 @@ class PlanService:
         # the tuning fingerprint is folded into every executable key:
         # a re-tune (new measured table) retires stale executables
         # lowered under the old policy instead of replaying them.
-        return self._execs.get_or_create(
-            ekey + (tuning.tuning_fingerprint(),), build
-        )
+        def compile_():
+            with span(COMPILE):
+                return build()
+
+        with span(EXEC_CACHE):
+            return self._execs.get_or_create(
+                ekey + (tuning.tuning_fingerprint(),), compile_
+            )
 
     def _fill_executable(self, key, pat: SparsePattern, vals_shape,
                          vals_dtype, batch: int | None = None):
@@ -485,19 +494,25 @@ class PlanService:
         :func:`repro.sparse.fsparse`; a hot structure pays only one
         compiled O(L) fill executable call.
         """
-        key, pat, coo = plan_lookup(
-            ii, jj, ss, shape, nzmax,
-            method=self.method if method is None else method, accum=accum,
-        )
-        if not isinstance(pat, SparsePattern):
-            # sharded plans run their own distributed fill (no AOT tier:
-            # executables would pin one mesh layout per entry)
-            return pat.assemble(coo.vals)
-        maybe_validate_pattern(pat, subject="PlanService.assemble")
-        self._persist("plan", key, pat)
-        fill = self._fill_executable(key, pat, coo.vals.shape,
-                                     coo.vals.dtype)
-        return self._wrap(pat, fill(coo.vals))
+        with span(ASSEMBLE, request=next(self._requests)) as request:
+            key, pat, coo = plan_lookup(
+                ii, jj, ss, shape, nzmax,
+                method=self.method if method is None else method,
+                accum=accum,
+            )
+            request.set_metadata(L=coo.L)
+            if not isinstance(pat, SparsePattern):
+                # sharded plans run their own distributed fill (no AOT
+                # tier: executables would pin one mesh layout per entry)
+                with span(FILL):
+                    return pat.assemble(coo.vals)
+            maybe_validate_pattern(pat, subject="PlanService.assemble")
+            self._persist("plan", key, pat)
+            fill = self._fill_executable(key, pat, coo.vals.shape,
+                                         coo.vals.dtype)
+            with span(FILL):
+                data = fill(coo.vals)
+            return self._wrap(pat, data)
 
     def assemble_many(self, requests, *, method: str | None = None,
                       accum: str = "sum") -> list:
@@ -511,39 +526,40 @@ class PlanService:
         results come back in request order, bit-identical to per-request
         :meth:`assemble`.
         """
-        looked = []
-        for req in requests:
-            ii, jj, ss = req[0], req[1], req[2]
-            shape = req[3] if len(req) > 3 else None
-            looked.append(plan_lookup(
-                ii, jj, ss, shape,
-                method=self.method if method is None else method,
-                accum=accum,
-            ))
-        groups: dict = {}
-        for idx, (key, _, coo) in enumerate(looked):
-            groups.setdefault((key, coo.vals.dtype.str), []).append(idx)
-        results: list = [None] * len(looked)
-        for (key, _), idxs in groups.items():
-            pat = looked[idxs[0]][1]
-            if not isinstance(pat, SparsePattern):
-                for i in idxs:
-                    results[i] = pat.assemble(looked[i][2].vals)
-                continue
-            self._persist("plan", key, pat)
-            vals0 = looked[idxs[0]][2].vals
-            if len(idxs) == 1:
+        with span(ASSEMBLE_MANY, request=next(self._requests)):
+            looked = []
+            for req in requests:
+                ii, jj, ss = req[0], req[1], req[2]
+                shape = req[3] if len(req) > 3 else None
+                looked.append(plan_lookup(
+                    ii, jj, ss, shape,
+                    method=self.method if method is None else method,
+                    accum=accum,
+                ))
+            groups: dict = {}
+            for idx, (key, _, coo) in enumerate(looked):
+                groups.setdefault((key, coo.vals.dtype.str), []).append(idx)
+            results: list = [None] * len(looked)
+            for (key, _), idxs in groups.items():
+                pat = looked[idxs[0]][1]
+                if not isinstance(pat, SparsePattern):
+                    for i in idxs:
+                        results[i] = pat.assemble(looked[i][2].vals)
+                    continue
+                self._persist("plan", key, pat)
+                vals0 = looked[idxs[0]][2].vals
+                if len(idxs) == 1:
+                    fill = self._fill_executable(key, pat, vals0.shape,
+                                                 vals0.dtype)
+                    results[idxs[0]] = self._wrap(pat, fill(vals0))
+                    continue
                 fill = self._fill_executable(key, pat, vals0.shape,
-                                             vals0.dtype)
-                results[idxs[0]] = self._wrap(pat, fill(vals0))
-                continue
-            fill = self._fill_executable(key, pat, vals0.shape, vals0.dtype,
-                                         batch=len(idxs))
-            stacked = jnp.stack([looked[i][2].vals for i in idxs])
-            data_b = fill(stacked)
-            for b, i in enumerate(idxs):
-                results[i] = self._wrap(pat, data_b[b])
-        return results
+                                             vals0.dtype, batch=len(idxs))
+                stacked = jnp.stack([looked[i][2].vals for i in idxs])
+                data_b = fill(stacked)
+                for b, i in enumerate(idxs):
+                    results[i] = self._wrap(pat, data_b[b])
+            return results
 
     def update_structure(self, ii, jj, ss, add_ii, add_jj, add_ss,
                          shape=None, nzmax: int | None = None, *,
@@ -567,36 +583,37 @@ class PlanService:
         :meth:`assemble` over the concatenated surviving + delta
         triplets).
         """
-        res = plan_update(
-            ii, jj, ss, add_ii, add_jj, add_ss, shape, nzmax,
-            drop_mask=drop_mask,
-            method=self.method if method is None else method,
-            accum=accum, nzmax_slack=nzmax_slack,
-        )
-        if res.pattern is not res.old_pattern:
-            from .spgemm import _structure_key
+        with span(UPDATE_STRUCTURE, request=next(self._requests)):
+            res = plan_update(
+                ii, jj, ss, add_ii, add_jj, add_ss, shape, nzmax,
+                drop_mask=drop_mask,
+                method=self.method if method is None else method,
+                accum=accum, nzmax_slack=nzmax_slack,
+            )
+            if res.pattern is not res.old_pattern:
+                from .spgemm import _structure_key
 
-            old_sk = _structure_key(res.old_pattern)
+                old_sk = _structure_key(res.old_pattern)
 
-            def _stale(ekey) -> bool:
-                kind = ekey[0]
-                if kind == "fill":
-                    return ekey[1] == res.old_key
-                if kind == "multiply":
-                    return old_sk in (ekey[1][0], ekey[1][1])
-                if kind == "spmv":
-                    return ekey[2] == old_sk
-                return False
+                def _stale(ekey) -> bool:
+                    kind = ekey[0]
+                    if kind == "fill":
+                        return ekey[1] == res.old_key
+                    if kind == "multiply":
+                        return old_sk in (ekey[1][0], ekey[1][1])
+                    if kind == "spmv":
+                        return ekey[2] == old_sk
+                    return False
 
-            self._execs.purge(_stale)
-            self._retire_persisted(res.old_key, old_sk)
-        maybe_validate_pattern(res.pattern,
-                               subject="PlanService.update_structure")
-        self._persist("plan", res.key, res.pattern)
-        fill = self._fill_executable(res.key, res.pattern,
-                                     res.coo.vals.shape,
-                                     res.coo.vals.dtype)
-        return self._wrap(res.pattern, fill(res.coo.vals))
+                self._execs.purge(_stale)
+                self._retire_persisted(res.old_key, old_sk)
+            maybe_validate_pattern(res.pattern,
+                                   subject="PlanService.update_structure")
+            self._persist("plan", res.key, res.pattern)
+            fill = self._fill_executable(res.key, res.pattern,
+                                         res.coo.vals.shape,
+                                         res.coo.vals.dtype)
+            return self._wrap(res.pattern, fill(res.coo.vals))
 
     def multiply(self, A, B, *, method: str | None = None,
                  nzmax: int | None = None,
@@ -607,22 +624,23 @@ class PlanService:
         comes from the shared SpGEMM LRU (and is persisted), the
         O(flops) numeric refill from a compiled executable.
         """
-        Ac = convert(A, "csc")
-        Bc = convert(B, "csc")
-        key, pp = product_lookup(Ac, Bc, method=method, nzmax=nzmax,
-                                 flops_max=flops_max)
-        maybe_validate_pattern(pp, subject="PlanService.multiply")
-        self._persist("product", key, pp)
-        ekey = ("multiply", key, Ac.data.dtype.str, Bc.data.dtype.str)
+        with span(MULTIPLY, request=next(self._requests)):
+            Ac = convert(A, "csc")
+            Bc = convert(B, "csc")
+            key, pp = product_lookup(Ac, Bc, method=method, nzmax=nzmax,
+                                     flops_max=flops_max)
+            maybe_validate_pattern(pp, subject="PlanService.multiply")
+            self._persist("product", key, pp)
+            ekey = ("multiply", key, Ac.data.dtype.str, Bc.data.dtype.str)
 
-        def build():
-            jitted = jax.jit(pp.multiply)
-            return jitted.lower(
-                jax.ShapeDtypeStruct(Ac.data.shape, Ac.data.dtype),
-                jax.ShapeDtypeStruct(Bc.data.shape, Bc.data.dtype),
-            ).compile()
+            def build():
+                jitted = jax.jit(pp.multiply)
+                return jitted.lower(
+                    jax.ShapeDtypeStruct(Ac.data.shape, Ac.data.dtype),
+                    jax.ShapeDtypeStruct(Bc.data.shape, Bc.data.dtype),
+                ).compile()
 
-        return self._aot(ekey, build)(Ac.data, Bc.data)
+            return self._aot(ekey, build)(Ac.data, Bc.data)
 
     def spmv(self, S, x):
         """``S @ x`` (dense vector/matrix) via a per-structure executable.
@@ -632,37 +650,39 @@ class PlanService:
         column/row-compressed structure (e.g. sharded block formats)
         fall back to the ordinary ``ops.matmul`` dispatch.
         """
-        x = jnp.asarray(x)
-        if x.ndim not in (1, 2):
-            raise ValueError(
-                f"spmv expects a vector or matrix, got ndim={x.ndim}"
-            )
-        fn, Sr = spmv_impl(S)
-        fields = _SPMV_NUMERIC_FIELDS.get(type(Sr).__name__)
-        if fields is None or not hasattr(Sr, "indices"):
-            return _ops_matmul(Sr, x)
-        from .spgemm import _structure_key
+        with span(SPMV, request=next(self._requests)):
+            x = jnp.asarray(x)
+            if x.ndim not in (1, 2):
+                raise ValueError(
+                    f"spmv expects a vector or matrix, got ndim={x.ndim}"
+                )
+            fn, Sr = spmv_impl(S)
+            fields = _SPMV_NUMERIC_FIELDS.get(type(Sr).__name__)
+            if fields is None or not hasattr(Sr, "indices"):
+                return _ops_matmul(Sr, x)
+            from .spgemm import _structure_key
 
-        nums = tuple(getattr(Sr, f) for f in fields)
-        ekey = ("spmv", type(Sr).__name__, _structure_key(Sr),
-                tuple(n.dtype.str for n in nums),
-                getattr(Sr, "block", None), tuple(x.shape), x.dtype.str)
+            nums = tuple(getattr(Sr, f) for f in fields)
+            ekey = ("spmv", type(Sr).__name__, _structure_key(Sr),
+                    tuple(n.dtype.str for n in nums),
+                    getattr(Sr, "block", None), tuple(x.shape), x.dtype.str)
 
-        def build():
-            def f(*args):
-                *vals, xv = args
-                A = dataclasses.replace(Sr, **dict(zip(fields, vals)))
-                if xv.ndim == 1:
-                    return fn(A, xv)
-                return jax.vmap(lambda col: fn(A, col),
-                                in_axes=1, out_axes=1)(xv)
+            def build():
+                @jax.named_scope("spmv")
+                def f(*args):
+                    *vals, xv = args
+                    A = dataclasses.replace(Sr, **dict(zip(fields, vals)))
+                    if xv.ndim == 1:
+                        return fn(A, xv)
+                    return jax.vmap(lambda col: fn(A, col),
+                                    in_axes=1, out_axes=1)(xv)
 
-            return jax.jit(f).lower(
-                *(jax.ShapeDtypeStruct(n.shape, n.dtype) for n in nums),
-                jax.ShapeDtypeStruct(x.shape, x.dtype),
-            ).compile()
+                return jax.jit(f).lower(
+                    *(jax.ShapeDtypeStruct(n.shape, n.dtype) for n in nums),
+                    jax.ShapeDtypeStruct(x.shape, x.dtype),
+                ).compile()
 
-        return self._aot(ekey, build)(*nums, x)
+            return self._aot(ekey, build)(*nums, x)
 
     # -- introspection -----------------------------------------------------
     @staticmethod

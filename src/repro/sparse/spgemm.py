@@ -157,10 +157,11 @@ class ProductPattern:
                 f"data_B has shape {data_B.shape} but this product was "
                 f"planned for a B with nzmax={self.b_capacity}"
             )
-        data = _multiply_vjp(
-            self.nzmax, self.sa, self.sb, self.pattern.slot,
-            data_A, data_B,
-        )
+        with jax.named_scope("multiply"):
+            data = _multiply_vjp(
+                self.nzmax, self.sa, self.sb, self.pattern.slot,
+                data_A, data_B,
+            )
         return CSC(
             data=data,
             indices=self.pattern.indices,

@@ -11,6 +11,7 @@ only one process may load the TPU library, so these tests stay in this
 one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,28 @@ def test_radix_planner_compiles_for_v5e(one_chip):
 
     keys = _shape(one_chip, (L,))
     assert "tpu_custom_call" in _compiled_text(plan_radix, keys, keys)
+
+
+def test_plan_scopes_keep_kernel_names_for_v5e(one_chip, monkeypatch):
+    """``plan`` itself, as the chip traces it: its named scopes reach
+    the ops' metadata, while the module and the Pallas kernels keep the
+    names the benchmark's trace readers match (``jit_plan``,
+    ``digit_block_histogram``, ``digit_placement``).  A small odd L, so
+    no other test reuses the trace made with the chip's backend."""
+    from repro.sparse.pattern import plan
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    keys = _shape(one_chip, (200_003,))
+    try:
+        text = plan.lower(keys, keys, shape=(5001, 5001),
+                          method="radix").compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert text.startswith("HloModule jit_plan,")
+    for kernel in ("digit_block_histogram", "digit_placement"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text), kernel
+    for scope in ("plan.sort", "plan.compress"):
+        assert f"/{scope}/" in text, scope
 
 
 def test_served_fill_compiles_for_v5e(one_chip):
