@@ -90,9 +90,15 @@ def coo_from_matlab(ii, jj, ss, shape=None) -> COO:
         return COO(
             rows=jnp.asarray(ii - 1),
             cols=jnp.asarray(jj - 1),
-            vals=jnp.asarray(ss.astype(np.float32)),
+            vals=upload_values(ss),
             shape=(M, N),
         )
+
+
+def upload_values(ss: np.ndarray) -> jax.Array:
+    """Device float32 copy of flat float64 triplet values: the values
+    part of every upload, cold (:func:`coo_from_matlab`) or warm."""
+    return jnp.asarray(ss.astype(np.float32))
 
 
 @partial(jax.jit, static_argnames=("M", "N"))

@@ -28,11 +28,14 @@ MULTIPLY = "sparse.multiply"
 SPMV = "sparse.spmv"
 FSPARSE = "sparse.fsparse"
 
-#: stage spans, in the order a request crosses them
+#: stage spans, in the order a cold request crosses them; a warm one
+#: (index vectors the plan LRU already holds) crosses only PLAN_KEY,
+#: UPLOAD (values only), EXEC_CACHE and FILL
 EXPAND = "sparse.expand"          # fsparse index expansion to float64
 VALIDATE = "sparse.validate"      # index checks and int32 casts
 UPLOAD = "sparse.upload"          # zero-offset, float32, copies (bytes)
-PLAN_KEY = "sparse.plan_key"      # structure key from the indices (bytes)
+PLAN_KEY = "sparse.plan_key"      # structure key, or the warm compare
+#                                   of the raw indices (bytes, hit)
 PLAN_CACHE = "sparse.plan_cache"  # plan LRU lookup
 PLAN = "sparse.plan"              # the symbolic phase, when it runs
 EXEC_CACHE = "sparse.exec_cache"  # executable LRU lookup

@@ -49,6 +49,8 @@ from .errors import InvariantViolation
 
 __all__ = ["LRUCache", "env_capacity"]
 
+_ABSENT = object()
+
 
 def _env_sanitize() -> bool:
     return os.environ.get("REPRO_LOCK_SANITIZE", "") \
@@ -136,6 +138,27 @@ class LRUCache:
                 return default
             self._data.move_to_end(key)
             self._hits += 1
+            return val
+
+    def get_verified(self, key: Hashable, verify: Callable[[Any], bool],
+                     default: Any = None) -> Any:
+        """:meth:`get` for entries that only *may* answer ``key``.
+
+        A found value is a hit (and bumps recency) only if
+        ``verify(value)`` is true; otherwise, or when ``key`` is absent,
+        the lookup counts as a miss and returns ``default``.  ``verify``
+        runs outside the lock, so it may be slow.
+        """
+        with self._locked():
+            val = self._data.get(key, _ABSENT)
+        ok = val is not _ABSENT and verify(val)
+        with self._locked():
+            if not ok:
+                self._misses += 1
+                return default
+            self._hits += 1
+            if key in self._data:
+                self._data.move_to_end(key)
             return val
 
     def insert(self, key: Hashable, value: Any) -> Any:

@@ -17,15 +17,16 @@ all implemented on :func:`repro.sparse.plan` + ``SparsePattern``:
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple
 
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.coo import COO, coo_from_matlab
+from ..core.coo import COO, coo_from_matlab, upload_values
 from ..core.csc import CSC, slot_columns
 from ..core.spans import (EXPAND, FSPARSE, PLAN, PLAN_CACHE, PLAN_KEY,
-                          FILL, span)
+                          FILL, UPLOAD, span)
 from .dispatch import resolve_method
 from .lru import LRUCache
 from .pattern import (SparsePattern, plan_coo, plan_symmetric,
@@ -46,39 +47,48 @@ def expand_indices(ii, jj, ss):
     with span(EXPAND):
         ii = np.asarray(ii, dtype=np.float64)
         jj = np.asarray(jj, dtype=np.float64)
-        ss = np.asarray(ss, dtype=np.float64)
-        if ii.ndim <= 1 and jj.ndim <= 1:
-            if ii.size == jj.size:
-                if ss.size == 1:
-                    ss = np.full(ii.shape, float(ss.ravel()[0]))
-                elif ss.size != ii.size:
-                    raise ValueError("vectors must be the same length")
-                return ii.ravel(), jj.ravel(), ss.ravel()
-            if ii.size != 1 and jj.size != 1:
-                # mismatched 1-d vectors are an error in Matlab, not an
-                # implicit outer product (only scalars broadcast)
-                raise ValueError("vectors must be the same length")
-        # outer-product expansion: i column (ni, 1), j row (1, nj) -> (ni, nj)
-        ii2 = ii.reshape(-1, 1)
-        jj2 = jj.reshape(1, -1)
-        ni, nj = ii2.shape[0], jj2.shape[1]
-        grid_i = np.broadcast_to(ii2, (ni, nj))
-        grid_j = np.broadcast_to(jj2, (ni, nj))
-        if ss.size == 1:
-            grid_s = np.full((ni, nj), float(ss.ravel()[0]))
-        elif ss.shape == (ni, nj):
-            grid_s = ss
-        elif ss.ndim == 1 and ss.size == ni * nj:
-            grid_s = ss.reshape(ni, nj)
-        elif ss.ndim == 2 and ss.shape in ((ni, 1), (1, nj)):
-            grid_s = np.broadcast_to(ss, (ni, nj))
-        else:
-            raise ValueError(
-                f"cannot expand s of shape {ss.shape} over a ({ni}, {nj}) "
-                f"index grid; expected a scalar, ({ni}, {nj}), ({ni}, 1), "
-                f"(1, {nj}), or a flat vector of {ni * nj} values"
-            )
-        return grid_i.ravel(), grid_j.ravel(), grid_s.ravel()
+        grid = _index_grid(ii, jj)
+        ss = expand_values(ss, grid)
+        if len(grid) == 1:
+            return ii.ravel(), jj.ravel(), ss
+        # outer-product expansion: i column (ni, 1), j row (1, nj)
+        return (np.broadcast_to(ii.reshape(-1, 1), grid).ravel(),
+                np.broadcast_to(jj.reshape(1, -1), grid).ravel(), ss)
+
+
+def _index_grid(ii: np.ndarray, jj: np.ndarray) -> tuple:
+    """Shape of the triplet grid ``ii``/``jj`` expand to: ``(L,)``
+    elementwise, ``(ni, nj)`` as an outer product."""
+    if ii.ndim <= 1 and jj.ndim <= 1:
+        if ii.size == jj.size:
+            return (ii.size,)
+        if ii.size != 1 and jj.size != 1:
+            # mismatched 1-d vectors are an error in Matlab, not an
+            # implicit outer product (only scalars broadcast)
+            raise ValueError("vectors must be the same length")
+    return (ii.size, jj.size)
+
+
+def expand_values(ss, grid: tuple) -> np.ndarray:
+    """The flat float64 values of an expansion over ``grid``
+    (:func:`_index_grid`), with the Matlab-compatible errors."""
+    ss = np.asarray(ss, dtype=np.float64)
+    if ss.size == 1:
+        return np.full(grid, float(ss.ravel()[0])).ravel()
+    if len(grid) == 1:
+        if ss.size != grid[0]:
+            raise ValueError("vectors must be the same length")
+        return ss.ravel()
+    ni, nj = grid
+    if ss.shape == grid or (ss.ndim == 1 and ss.size == ni * nj):
+        return ss.ravel()
+    if ss.ndim == 2 and ss.shape in ((ni, 1), (1, nj)):
+        return np.broadcast_to(ss, grid).ravel()
+    raise ValueError(
+        f"cannot expand s of shape {ss.shape} over a ({ni}, {nj}) "
+        f"index grid; expected a scalar, ({ni}, {nj}), ({ni}, 1), "
+        f"(1, {nj}), or a flat vector of {ni * nj} values"
+    )
 
 
 def fsparse(ii, jj, ss, shape=None, nzmax: int | None = None,
@@ -268,10 +278,10 @@ def plan_lookup(ii, jj, ss, shape=None, nzmax: int | None = None,
 
     Validates/expands the Matlab-style request, resolves its cache key
     and returns ``(key, pattern, coo)`` with ``pattern`` served from
-    (or inserted into) the thread-safe plan LRU.  ``sparse2`` is this
-    plus ``pattern.assemble``; :class:`repro.sparse.serving.PlanService`
-    is this plus the AOT executable tier — one code path, so the two
-    entry points cannot drift apart.
+    (or inserted into) the thread-safe plan LRU.  ``sparse2`` and
+    :class:`repro.sparse.serving.PlanService` reach it through
+    :func:`_lookup_values`, which serves a warm request without it —
+    one code path, so the entry points cannot drift apart.
 
     ``nzmax_slack`` folds into the resolved ``nzmax`` (``L + slack``)
     *before* keying, so a slack-planned structure and an explicit
@@ -300,7 +310,7 @@ def plan_lookup(ii, jj, ss, shape=None, nzmax: int | None = None,
     # block size — a SymPattern and a SparsePattern over the same
     # triplets are different resident plans
     rows, cols = coo.rows, coo.cols
-    with span(PLAN_KEY, bytes=rows.nbytes + cols.nbytes):
+    with span(PLAN_KEY, bytes=rows.nbytes + cols.nbytes, hit=0):
         key = _cache_key(np.asarray(rows), np.asarray(cols),
                          coo.shape, nzmax, method,
                          (accum, format, int(block)) + tuple(extra))
@@ -318,6 +328,120 @@ def plan_lookup(ii, jj, ss, shape=None, nzmax: int | None = None,
     with span(PLAN_CACHE):
         pat = _PLAN_CACHE.get_or_create(key, build)
     return key, pat, coo
+
+
+# ---------------------------------------------------------------------------
+# The warm path: raw index vectors as aliases of a plan-LRU key
+# ---------------------------------------------------------------------------
+#: raw request -> canonical plan-LRU key, for index vectors that a
+#: successful lookup validated.  Keyed on what the caller passed (index
+#: dtypes and shapes, a strided sample of their elements, and every
+#: argument that enters the plan key); a hit still needs every index
+#: byte to match the copy stored here.  Sized like the plan LRU.
+_ALIASES = LRUCache(32, name="sparse2-alias", env="REPRO_PLAN_CACHE_SIZE")
+
+#: elements of each index vector sampled into the alias key
+_ALIAS_SAMPLE = 16
+
+
+class _Alias(NamedTuple):
+    ii: np.ndarray   # read-only copies of the validated index vectors
+    jj: np.ndarray
+    grid: tuple      # what they expand to (``_index_grid``)
+    key: tuple       # the plan-LRU key they resolved to
+
+
+def _alias_key(ii: np.ndarray, jj: np.ndarray, shape, args: tuple):
+    """The alias-store key of a request, or None where the request can
+    only take the cold path (an index dtype without a plain byte
+    compare, a ``shape`` the cold path would reject)."""
+    for v in (ii, jj):
+        if v.dtype.kind not in "iuf" or v.itemsize not in (1, 2, 4, 8):
+            return None
+    if shape is not None:
+        try:
+            shape = (int(shape[0]), int(shape[1]))
+        except (TypeError, ValueError, IndexError):
+            return None
+    sample = tuple(v.flat[np.linspace(0, v.size - 1, _ALIAS_SAMPLE,
+                                      dtype=np.intp)].tobytes()
+                   if v.size else b"" for v in (ii, jj))
+    return (ii.dtype.str, ii.shape, jj.dtype.str, jj.shape, shape,
+            *sample, *args)
+
+
+def _same(a: np.ndarray, b: np.ndarray, chunk: int = 1 << 18) -> bool:
+    """Every byte of ``a`` (the caller's) equals ``b`` (a C-contiguous
+    copy of one dtype and shape); compared a cache-sized chunk at a
+    time, as unsigned integers so that floats compare by bits."""
+    u = np.dtype(f"u{a.itemsize}")
+    a, b = a.reshape(-1).view(u), b.reshape(-1).view(u)
+    return all(np.array_equal(a[s:s + chunk], b[s:s + chunk])
+               for s in range(0, a.size, chunk))
+
+
+def _frozen(v: np.ndarray) -> np.ndarray:
+    out = np.array(v, order="C")
+    out.flags.writeable = False
+    return out
+
+
+def _lookup_values(ii, jj, ss, shape=None, nzmax: int | None = None,
+                   *, method: str | None = None, mesh=None,
+                   accum: str = "sum", nzmax_slack: int = 0,
+                   format: str | None = None, block: int = 1):
+    """:func:`plan_lookup` for callers that need only the values:
+    returns ``(key, pattern, vals)``, ``vals`` the float32 device values.
+
+    Index vectors byte-identical (same dtype and shape) to ones an
+    earlier successful lookup validated, with the same arguments, are a
+    warm request: it skips expansion, validation, the index upload and
+    the key, checks and uploads only the values, and returns the very
+    key object that lookup stored.  Everything else takes
+    :func:`plan_lookup`'s path unchanged and, when that returns, is
+    recorded for the next call.
+    """
+    method = method if method == "sharded" else resolve_method(method)
+    validate_accum(accum)
+    _validate_format(format, block)
+    ii, jj = np.asarray(ii), np.asarray(jj)
+    mesh_id = ()
+    if method == "sharded":
+        from .sharded import mesh_fingerprint, resolve_mesh
+
+        mesh = resolve_mesh(mesh)
+        mesh_id = mesh_fingerprint(mesh, "data")
+    akey = None
+    if method == "sharded" or mesh is None:  # else the cold path raises
+        akey = _alias_key(ii, jj, shape, (nzmax, method, accum, nzmax_slack,
+                                          format, int(block), mesh_id))
+    if akey is not None:
+        hit = _ALIASES.get_verified(akey, lambda a: _warm(ii, jj, a))
+        if hit is not None:
+            pat = _PLAN_CACHE.get(hit.key)
+            if pat is not None:
+                with span(UPLOAD, bytes=4 * math.prod(hit.grid)):
+                    vals = upload_values(expand_values(ss, hit.grid))
+                return hit.key, pat, vals
+    key, pat, coo = plan_lookup(ii, jj, ss, shape, nzmax, method=method,
+                                mesh=mesh, accum=accum,
+                                nzmax_slack=nzmax_slack, format=format,
+                                block=block)
+    if akey is not None:
+        _ALIASES.pop(akey)
+        _ALIASES.insert(akey, _Alias(_frozen(ii), _frozen(jj),
+                                     _index_grid(ii, jj), key))
+    return key, pat, coo.vals
+
+
+def _warm(ii: np.ndarray, jj: np.ndarray, alias: _Alias) -> bool:
+    """Whether ``alias`` answers the request: every index byte equal and
+    its plan still in the plan LRU."""
+    with span(PLAN_KEY, bytes=ii.nbytes + jj.nbytes) as s:
+        ok = (_same(ii, alias.ii) and _same(jj, alias.jj)
+              and alias.key in _PLAN_CACHE)
+        s.set_metadata(hit=int(ok))
+    return ok
 
 
 def sparse2(ii, jj, ss, shape=None, nzmax: int | None = None,
@@ -343,11 +467,11 @@ def sparse2(ii, jj, ss, shape=None, nzmax: int | None = None,
     result into dense ``block x block`` tiles.  The format (and block)
     are part of the cache key.
     """
-    _, pat, coo = plan_lookup(ii, jj, ss, shape, nzmax, method=method,
-                              mesh=mesh, accum=accum,
-                              nzmax_slack=nzmax_slack, format=format,
-                              block=block)
-    out = pat.assemble(coo.vals)
+    _, pat, vals = _lookup_values(ii, jj, ss, shape, nzmax, method=method,
+                                  mesh=mesh, accum=accum,
+                                  nzmax_slack=nzmax_slack, format=format,
+                                  block=block)
+    out = pat.assemble(vals)
     if format == "bsr":
         from .formats import convert
 
@@ -484,8 +608,15 @@ def plan_cache_info() -> dict:
     return _PLAN_CACHE.info()
 
 
+def alias_cache_info() -> dict:
+    """The warm path's alias store (:func:`_lookup_values`): its
+    ``hits`` are requests served without touching their indices."""
+    return _ALIASES.info()
+
+
 def plan_cache_clear() -> None:
     _PLAN_CACHE.clear()
+    _ALIASES.clear()
 
 
 # ---------------------------------------------------------------------------

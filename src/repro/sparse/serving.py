@@ -88,7 +88,8 @@ from .analysis.invariants import maybe_validate_pattern, validate_pattern
 from .errors import CacheCorruptionWarning, InvariantViolation
 from .formats import convert
 from .lru import LRUCache
-from .matlab import plan_cache_info, plan_lookup, plan_update, _PLAN_CACHE
+from .matlab import (alias_cache_info, plan_cache_info, plan_update,
+                     _lookup_values, _PLAN_CACHE)
 from .ops import matmul as _ops_matmul, spmv_impl
 from .pattern import SparsePattern
 from .spgemm import (
@@ -495,23 +496,22 @@ class PlanService:
         compiled O(L) fill executable call.
         """
         with span(ASSEMBLE, request=next(self._requests)) as request:
-            key, pat, coo = plan_lookup(
+            key, pat, vals = _lookup_values(
                 ii, jj, ss, shape, nzmax,
                 method=self.method if method is None else method,
                 accum=accum,
             )
-            request.set_metadata(L=coo.L)
+            request.set_metadata(L=vals.shape[0])
             if not isinstance(pat, SparsePattern):
                 # sharded plans run their own distributed fill (no AOT
                 # tier: executables would pin one mesh layout per entry)
                 with span(FILL):
-                    return pat.assemble(coo.vals)
+                    return pat.assemble(vals)
             maybe_validate_pattern(pat, subject="PlanService.assemble")
             self._persist("plan", key, pat)
-            fill = self._fill_executable(key, pat, coo.vals.shape,
-                                         coo.vals.dtype)
+            fill = self._fill_executable(key, pat, vals.shape, vals.dtype)
             with span(FILL):
-                data = fill(coo.vals)
+                data = fill(vals)
             return self._wrap(pat, data)
 
     def assemble_many(self, requests, *, method: str | None = None,
@@ -531,23 +531,23 @@ class PlanService:
             for req in requests:
                 ii, jj, ss = req[0], req[1], req[2]
                 shape = req[3] if len(req) > 3 else None
-                looked.append(plan_lookup(
+                looked.append(_lookup_values(
                     ii, jj, ss, shape,
                     method=self.method if method is None else method,
                     accum=accum,
                 ))
             groups: dict = {}
-            for idx, (key, _, coo) in enumerate(looked):
-                groups.setdefault((key, coo.vals.dtype.str), []).append(idx)
+            for idx, (key, _, vals) in enumerate(looked):
+                groups.setdefault((key, vals.dtype.str), []).append(idx)
             results: list = [None] * len(looked)
             for (key, _), idxs in groups.items():
                 pat = looked[idxs[0]][1]
                 if not isinstance(pat, SparsePattern):
                     for i in idxs:
-                        results[i] = pat.assemble(looked[i][2].vals)
+                        results[i] = pat.assemble(looked[i][2])
                     continue
                 self._persist("plan", key, pat)
-                vals0 = looked[idxs[0]][2].vals
+                vals0 = looked[idxs[0]][2]
                 if len(idxs) == 1:
                     fill = self._fill_executable(key, pat, vals0.shape,
                                                  vals0.dtype)
@@ -555,7 +555,7 @@ class PlanService:
                     continue
                 fill = self._fill_executable(key, pat, vals0.shape,
                                              vals0.dtype, batch=len(idxs))
-                stacked = jnp.stack([looked[i][2].vals for i in idxs])
+                stacked = jnp.stack([looked[i][2] for i in idxs])
                 data_b = fill(stacked)
                 for b, i in enumerate(idxs):
                     results[i] = self._wrap(pat, data_b[b])
@@ -694,6 +694,7 @@ class PlanService:
         """All cache tiers' metrics in one dict (the ops dashboard)."""
         return {
             "plan": plan_cache_info(),
+            "alias": alias_cache_info(),
             "product": product_cache_info(),
             "exec": self._execs.info(),
             "loaded_plans": self.loaded_plans,

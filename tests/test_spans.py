@@ -19,6 +19,8 @@ L, M, N = 600, 40, 30
 STAGES = [spans.EXPAND, spans.VALIDATE, spans.UPLOAD, spans.PLAN_KEY,
           spans.PLAN_CACHE, spans.PLAN, spans.EXEC_CACHE, spans.COMPILE,
           spans.FILL]
+#: those of a warm one: its raw indices are compared, only values go up
+WARM_STAGES = [spans.PLAN_KEY, spans.UPLOAD, spans.EXEC_CACHE, spans.FILL]
 
 
 def _host_spans(logdir) -> list:
@@ -50,7 +52,7 @@ def traced(tmp_path_factory):
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(logdir), profiler_options=opts)
     try:
-        for _ in range(2):   # cold, then warm
+        for _ in range(3):   # cold, then warm twice
             S = svc.assemble(ii, jj, rng.random(L), (M, N))
             S.data.block_until_ready()
         fsparse(ii, jj, rng.random(L), (M, N)).data.block_until_ready()
@@ -65,11 +67,11 @@ def traced(tmp_path_factory):
 
 def test_every_stage_in_order_inside_its_request(traced):
     events, outer = traced
-    cold, warm = outer[spans.ASSEMBLE]
+    cold, *warm = outer[spans.ASSEMBLE]
     (one_shot,) = outer[spans.FSPARSE]
     assert [e[0] for e in _inside(cold, events)] == STAGES
-    assert [e[0] for e in _inside(warm, events)] == [
-        s for s in STAGES if s not in (spans.PLAN, spans.COMPILE)]
+    for request in warm:
+        assert [e[0] for e in _inside(request, events)] == WARM_STAGES
     assert [e[0] for e in _inside(one_shot, events)] == [
         spans.EXPAND, spans.VALIDATE, spans.UPLOAD, spans.PLAN, spans.FILL]
 
@@ -83,13 +85,26 @@ def test_plan_and_compile_nest_in_their_cache_lookups(traced):
 
 def test_request_stats(traced):
     events, outer = traced
-    cold, warm = outer[spans.ASSEMBLE]
+    cold, warm, _ = outer[spans.ASSEMBLE]
     assert warm[3]["request"] == cold[3]["request"] + 1
     assert cold[3]["L"] == warm[3]["L"] == outer[spans.FSPARSE][0][3]["L"] == L
-    inner = {e[0]: e for e in _inside(warm, events)}
-    # int32 rows and cols, float32 values up; int32 rows and cols keyed
-    assert inner[spans.UPLOAD][3]["bytes"] == 12 * L
-    assert inner[spans.PLAN_KEY][3]["bytes"] == 8 * L
+
+
+@pytest.mark.parametrize("request_no,upload,plan_key,hit", [
+    # cold: int32 rows and cols, float32 values up; int32 rows and cols
+    # keyed
+    (0, 12 * L, 8 * L, 0),
+    # warm: float32 values up only; the caller's int64 indices compared
+    (1, 4 * L, 16 * L, 1),
+    (2, 4 * L, 16 * L, 1),
+])
+def test_stage_stats(traced, request_no, upload, plan_key, hit):
+    events, outer = traced
+    inner = {e[0]: e for e in _inside(outer[spans.ASSEMBLE][request_no],
+                                      events)}
+    assert inner[spans.UPLOAD][3]["bytes"] == upload
+    assert inner[spans.PLAN_KEY][3]["bytes"] == plan_key
+    assert inner[spans.PLAN_KEY][3]["hit"] == hit
 
 
 def _programs():
