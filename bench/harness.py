@@ -5,8 +5,10 @@ found by the name ``BENCHMARK.json`` gives it:
 
 * ``bench/configs/<config>.json``: sizes, source, limits, and the name
   of the generator module ``bench/generators/<generator>.py``;
-* ``bench/traffic/<traffic>.json``: the loop and its parameters
-  (:mod:`bench.loops`);
+* ``bench/traffic/<traffic>.json``: the name of its loop and the
+  loop's parameters.  ``refill`` and ``new`` are built into
+  :mod:`bench.loops`; any other loop is the ``LOOP`` class of
+  ``bench/traffic/<loop>.py`` (:func:`load_loop`);
 * ``bench/metrics/<metric>.py``: a reader ``read(ctx)`` that returns
   the metric's value, or ``None`` where it finds nothing to read.  A
   run of a cell that declares the metric and reads ``None`` fails with
@@ -14,7 +16,9 @@ found by the name ``BENCHMARK.json`` gives it:
 
 A run with ``--trace 0`` reports the cell's end-to-end metrics; one
 with ``--trace 1`` traces the same window and reports its per-layer
-metrics, the device's busy and window seconds and a breakdown.
+metrics, the device's busy and window seconds and a breakdown.  Both
+read every chip the cell asks for: the peak memory of each, and in the
+trace the operations of each (``Trace.ops_by_device``).
 """
 from __future__ import annotations
 
@@ -28,7 +32,7 @@ import time
 from pathlib import Path
 
 from . import check, peaks, tracereduce
-from .loops import LOOPS
+from .loops import LOOPS, Loop
 
 BENCH = Path(__file__).resolve().parent
 
@@ -66,6 +70,28 @@ def load_module(path: Path, name: str):
 def load_generator(bench: Path, name: str):
     return load_module(Path(bench) / "generators" / f"{name}.py",
                        f"bench_generator_{name}")
+
+
+class LoopNotFound(LookupError):
+    """A traffic mix names a loop that is neither built in nor a file
+    that defines ``LOOP``."""
+
+
+def load_loop(bench: Path, name: str) -> type:
+    """The loop class a traffic mix names: ``refill`` and ``new`` are
+    built into :mod:`bench.loops`; any other name is the ``LOOP`` of
+    ``bench/traffic/<name>.py``, a subclass of :class:`bench.loops.Loop`."""
+    if name in LOOPS:
+        return LOOPS[name]
+    path = Path(bench) / "traffic" / f"{name}.py"
+    if not path.is_file():
+        raise LoopNotFound(f"no loop {name!r}: {path} does not exist")
+    loop = getattr(load_module(path, "bench_loop_" + name.replace(".", "_")),
+                   "LOOP", None)
+    if not (isinstance(loop, type) and issubclass(loop, Loop)):
+        raise LoopNotFound(f"no loop {name!r}: {path} defines no LOOP, "
+                           f"a subclass of bench.loops.Loop")
+    return loop
 
 
 def load_reader(bench: Path, metric: str):
@@ -133,7 +159,9 @@ class NoChip(RuntimeError):
     """JAX found no TPU, or fewer chips than the cell asks for."""
 
 
-def find_device(chips: int, require_tpu: bool):
+def find_devices(chips: int, require_tpu: bool):
+    """``(devices, count, peak)``: the cell's devices (the first
+    ``chips`` that JAX sees), how many JAX sees, and the chip's peaks."""
     import jax
 
     devices = jax.devices()
@@ -148,7 +176,7 @@ def find_device(chips: int, require_tpu: bool):
         peak = peaks.peaks(dev.device_kind)
     else:
         peak = peaks.DEVICE_PEAKS["TPU v5 lite"]
-    return dev, len(devices), peak
+    return devices[:chips], len(devices), peak
 
 
 def compilation_cache(root: Path) -> str:
@@ -200,26 +228,33 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
     cell = find_cell(spec, workload)
     cfg = load_config(root, spec, cell["config"])
     traffic = load_traffic(bench, cell["traffic"])
+    try:
+        loop_class = load_loop(bench, traffic["loop"])
+    except LoopNotFound as e:
+        print(f"bench: {workload}: {e}", file=err)
+        return 3
     metrics = cell_metrics(spec, workload, trace)
     readers = {m["name"]: load_reader(bench, m["name"]) for m in metrics}
     generator = load_generator(bench, cfg["generator"])
 
     try:
-        dev, count, peak = find_device(int(cell["chips"]), require_tpu)
+        devices, count, peak = find_devices(int(cell["chips"]), require_tpu)
     except (NoChip, KeyError) as e:
         print(f"bench: {e}", file=err)
         return 2
     import jax
 
+    dev = devices[0]
     cache = compilation_cache(root)
     src = str(root / "src")
     if src not in sys.path:
         sys.path.insert(0, src)
-    print(f"bench: {workload} seed={seed} on {count} x {dev.device_kind}; "
-          f"compile cache {cache}", file=err)
+    print(f"bench: {workload} seed={seed} loop {loop_class.__name__} on "
+          f"{len(devices)} of {count} x {dev.device_kind}; compile cache "
+          f"{cache}", file=err)
 
     t0 = time.perf_counter()
-    loop = LOOPS[traffic["loop"]](cfg, traffic, seed, generator)
+    loop = loop_class(cfg, traffic, seed, generator)
     try:
         loop.setup()
         setup_s = time.perf_counter() - t0
@@ -236,7 +271,8 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
         finally:
             if trace:
                 jax.profiler.stop_trace()
-        memory_peak = (dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+        memory_peaks = [int((d.memory_stats() or {}).get(
+            "peak_bytes_in_use", 0)) for d in devices]
         loop.finish()
     finally:
         loop.close()
@@ -244,7 +280,7 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
 
     tr = None
     if trace:
-        tr = tracereduce.load(logdir, dev.id)
+        tr = tracereduce.load(logdir, [d.id for d in devices])
         shutil.rmtree(logdir, ignore_errors=True)
     ctx = Context(cfg=cfg, setup_s=setup_s,
                   latencies=latencies, triplets=loop.triplets(),
@@ -263,12 +299,15 @@ def run(root, workload: str, seed: int, seconds: float, trace: bool, *,
     ok, checks = check.verdict(numbers, cfg["limits"])
     correct = ok and failed == 0
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": count, "memory_peak_bytes": int(memory_peak)}
+              "count": count, "memory_peak_bytes": max(memory_peaks),
+              "memory_peak_bytes_by_device": memory_peaks}
     result = {"correct": correct, "attempted": len(latencies) + failed,
               "failed": failed, "metrics": values, "device": device}
     if trace:
         lo, hi = tr.window()
-        device["busy_s"] = tracereduce.busy(tr.ops, lo, hi) / 1e9
+        device["busy_s"] = statistics.mean(
+            tracereduce.busy(ops, lo, hi)
+            for ops in tr.ops_by_device.values()) / 1e9
         device["window_s"] = (hi - lo) / 1e9
         result["breakdown"] = {
             "device_ops": tracereduce.top_ops(tr.ops, lo, hi),

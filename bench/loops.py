@@ -1,11 +1,11 @@
-"""The one general traffic generator: closed loops over the program.
+"""The traffic loops: closed loops over the program.
 
 A traffic mix (``bench/traffic/<name>.json``) names a ``loop`` and its
 parameters; a configuration (``bench/configs/<name>.json``) names the
 ``generator`` module (``bench/generators/<name>.py``) that makes its
 triplets from the seed.  Every loop is a closed loop with one caller:
 the next request is sent when the last one has returned and its result
-is ready on the device.
+is ready on the device.  Two loops are built in:
 
 * ``refill``: one hot structure through ``PlanService.assemble`` with
   a new value vector every request, cycled from ``value_sets`` vectors
@@ -15,6 +15,35 @@ is ready on the device.
   A producer thread makes structure ``k`` from ``(seed, k)`` ahead of
   the caller, ``queue_depth`` deep, so making it is not timed; the
   caller's waits on it are reported as ``generator_wait_s``.
+
+Any other loop name ``<loop>`` is the class ``LOOP`` of the file
+``bench/traffic/<loop>.py``, a subclass of :class:`Loop`
+(``bench.harness.load_loop``), so a cell brings a loop of its own as a
+new file.  The protocol, in the order the harness calls it:
+
+* ``Loop(cfg, traffic, seed, generator)``: the configuration and the
+  traffic mix as parsed JSON, the run's seed, the generator module;
+* ``setup()``: make the inputs from the seed, plan, compile, and warm
+  every program the window will run (timed as ``setup_s``);
+* ``request(r)``: request ``r`` of the window (0, 1, ...), returning
+  when its result is ready on the device; it offers results to
+  ``self.sample``;
+* ``finish()``: after the window and the memory reading, copy the
+  sample to the host and drop the program's device state;
+* ``close()``: stop whatever the loop started; called on every exit,
+  also after a failure, and safe to call twice;
+* ``check(control) -> list``: one dict of numbers per sampled result
+  (:func:`bench.check.compare`), with the reference in bfloat16 put in
+  the program's place where ``control`` is true;
+* ``triplets()``: triplets per request, for the rates;
+* ``wait_s``: seconds the caller waited on the loop's own generator
+  (``generator_wait_s``).
+
+Two rules.  A loop whose result is not one CSC (a ``ShardedCSC``, a
+batch) converts it to the CSC the check compares after the window
+(in ``finish`` or ``check``), never inside it.  A loop keeps its set-up
+(planning, compiling, warming) out of the window, as
+:class:`RefillLoop` does: nothing compiles inside the window.
 
 Each loop keeps a seeded reservoir sample of ``check_sample`` results
 and rebuilds their references once the window has closed.

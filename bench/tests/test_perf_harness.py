@@ -1,7 +1,7 @@
 """The harness on the CPU at tiny sizes: discovery by name of files
-added in a temporary directory, a whole run without the chip, the
-bfloat16 control and planted faults failing the check, and the refusal
-to run without a TPU."""
+added in a temporary directory (loops among them), a whole run without
+the chip, on one device and on four, the bfloat16 control and planted
+faults failing the check, and the refusal to run without a TPU."""
 import io
 import json
 import os
@@ -15,9 +15,10 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
 
-from bench import harness  # noqa: E402
+from bench import harness, loops  # noqa: E402
 
 BENCH = ROOT / "bench"
+DATA = BENCH / "tests" / "data"
 
 TINY_CONFIG = {
     "name": "tiny41", "generator": "tiny_gen",
@@ -209,6 +210,115 @@ def test_planted_fault_is_not_correct(tiny_root, monkeypatch, workload,
     assert result["correct"] is False
     c = result["checks"][number]
     assert c["value"] > c["limit"]
+
+
+def _add_cell(root, traffic: str, mix: dict, chips: int = 1) -> str:
+    """Add the traffic mix ``traffic`` and a cell of it on ``tiny41``
+    to a checkout; returns the cell's name."""
+    (root / "bench" / "traffic" / f"{traffic}.json").write_text(
+        json.dumps(mix))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    name = f"tiny41.{traffic}"
+    spec["workloads"].append({"name": name, "config": "tiny41",
+                              "traffic": traffic, "chips": chips})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return name
+
+
+NAMED_LOOP = """from bench import loops
+
+
+class NamedRefill(loops.RefillLoop):
+    \"\"\"``refill``, found by the name of its file.\"\"\"
+
+
+LOOP = NamedRefill
+"""
+
+
+def test_loop_file_found_by_name_runs(tiny_root):
+    (tiny_root / "bench" / "traffic" / "refill_named.py").write_text(
+        NAMED_LOOP)
+    cell = _add_cell(tiny_root, "refill_named_small",
+                     {"loop": "refill_named", "value_sets": 2,
+                      "check_sample": 2})
+    loop = harness.load_loop(tiny_root / "bench", "refill_named")
+    assert loop.__name__ == "NamedRefill"
+    assert issubclass(loop, loops.RefillLoop)
+    rc, out, err = _run(tiny_root, cell)
+    assert rc == 0, err
+    assert "loop NamedRefill" in err
+    result = json.loads(out.strip().splitlines()[-1])
+    assert result["correct"] is True, err
+    assert result["failed"] == 0 and result["attempted"] >= 1
+    assert result["device"]["memory_peak_bytes_by_device"] == [
+        result["device"]["memory_peak_bytes"]]
+
+
+@pytest.mark.parametrize("source", [None, "LOOPS = {}\n"])
+def test_loop_not_found_fails_with_the_path(tiny_root, source):
+    path = tiny_root / "bench" / "traffic" / "no_such_loop.py"
+    if source is not None:   # a file that defines no LOOP
+        path.write_text(source)
+    cell = _add_cell(tiny_root, "broken", {"loop": "no_such_loop"})
+    rc, out, err = _run(tiny_root, cell)
+    assert rc != 0 and out == ""
+    assert str(path) in err
+
+
+@pytest.mark.parametrize("name,cls", [("refill", loops.RefillLoop),
+                                      ("new", loops.NewLoop)])
+def test_builtin_loops_resolve_as_before(name, cls):
+    assert harness.load_loop(BENCH, name) is cls
+    assert harness.load_loop(BENCH, name) is loops.LOOPS[name]
+
+
+SHARDED_RUN = """
+import io, json, sys
+from pathlib import Path
+sys.path[:0] = [{root!r}, {src!r}]
+from bench import harness
+
+out = []
+for control in (False, True):
+    o, e = io.StringIO(), io.StringIO()
+    rc = harness.run(Path({tiny!r}), {cell!r}, 2**31 + 11, 0.2, False,
+                     bench=Path({tiny!r}) / "bench", require_tpu=False,
+                     control=control, out=o, err=e)
+    assert rc == 0, e.getvalue()
+    out.append(json.loads(o.getvalue().strip().splitlines()[-1]))
+    out[-1]["stderr"] = e.getvalue()
+print(json.dumps(out))
+"""
+
+
+def test_loop_file_drives_the_sharded_path_on_four_devices(tiny_root):
+    """A cell's own loop over ``PlanService(method="sharded")`` on four
+    host devices: the block-row result is rebuilt as one CSC after the
+    window and checked, and every device's memory is read."""
+    shutil.copy(DATA / "sharded_refill.py",
+                tiny_root / "bench" / "traffic" / "sharded_refill.py")
+    cell = _add_cell(tiny_root, "sharded_small",
+                     {"loop": "sharded_refill", "value_sets": 2,
+                      "check_sample": 2}, chips=4)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = SHARDED_RUN.format(root=str(ROOT), src=str(ROOT / "src"),
+                              tiny=str(tiny_root), cell=cell)
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-4000:]
+    run, control = json.loads(p.stdout.strip().splitlines()[-1])
+    assert "loop ShardedRefillLoop on 4 of 4" in run["stderr"]
+    assert run["correct"] is True, run["stderr"]
+    assert run["failed"] == 0 and run["attempted"] >= 1
+    assert run["checks"]["structure_mismatches"]["value"] == 0
+    assert len(run["device"]["memory_peak_bytes_by_device"]) == 4
+    assert run["device"]["memory_peak_bytes"] == max(
+        run["device"]["memory_peak_bytes_by_device"])
+    assert control["correct"] is False
+    c = control["checks"]["data_rel_err"]
+    assert c["value"] > 10 * c["limit"]
 
 
 def test_no_tpu_exits_nonzero_with_no_result():

@@ -1,6 +1,7 @@
 """The readers of the program's stage spans on a synthetic trace: self
-time per request of the window, nested stages left out, nothing read
-where no span is, and every name matched being one the program opens."""
+time per request of the window, nested stages left out, span stats per
+request, nothing read where no span is, and every name matched being
+one the program opens."""
 import sys
 from pathlib import Path
 
@@ -39,7 +40,8 @@ OUTSIDE = [("sparse.expand", 50000, 4000), ("sparse.fill", 60000, 900)]
 
 
 def _ctx(spans_):
-    host = [E("bench.window", 0, 100000)] + [E(*s) for s in spans_]
+    host = [E("bench.window", 0, 100000)] + [
+        E(*s) if len(s) == 3 else E(*s[:3], "", s[3]) for s in spans_]
     ops = [E("fusion", 8200, 1000, "jit_scatter"),
            E("fusion", 21800, 150, "jit_scatter")]
     trace = tracereduce.build(ops, [], host)
@@ -74,6 +76,50 @@ def test_nested_stage_left_out_of_the_lookup_around_it():
     assert spantime.self_ms(ctx, (spans.PLAN,)) == pytest.approx(1.8e-3)
     assert spantime.self_ms(ctx, (spans.EXEC_CACHE,)) == pytest.approx(2e-4)
     assert spantime.self_ms(ctx, (spans.COMPILE,)) == pytest.approx(3.3e-3)
+
+
+# the same requests with the stats the program puts on its spans: a
+# cold request's key misses and uploads rows, cols and values, a warm
+# one hits and uploads the values alone
+STATS = {("sparse.upload", 2000): {"bytes": 120},
+         ("sparse.plan_key", 2300): {"bytes": 80, "hit": 0},
+         ("sparse.upload", 21000): {"bytes": 40},
+         ("sparse.plan_key", 21300): {"bytes": 80, "hit": 1},
+         ("sparse.assemble", 20000): {"request": 7, "L": 10}}
+WITH_STATS = [s + (STATS[s[:2]],) if s[:2] in STATS else s
+              for s in COLD + WARM + CUT + OUTSIDE]
+
+
+def test_span_stats_kept_through_build():
+    ctx = _ctx(WITH_STATS)
+    hits = [(s.start, s.stats) for s in ctx.trace.spans(spans.PLAN_KEY)]
+    assert hits == [(2300, {"bytes": 80, "hit": 0}),
+                    (21300, {"bytes": 80, "hit": 1})]
+    assert ctx.trace.spans(spans.PLAN)[0].stats == {}
+
+
+@pytest.mark.parametrize("names,key,per_request", [
+    ((spans.UPLOAD,), "bytes", (120 + 40) / 2),
+    ((spans.PLAN_KEY,), "hit", 1 / 2),
+    ((spans.PLAN_KEY,), "bytes", 80),
+    ((spans.UPLOAD, spans.PLAN_KEY), "bytes", (120 + 40 + 80 + 80) / 2),
+    ((spans.ASSEMBLE,), "L", 10 / 2),
+])
+def test_span_stat_per_request_of_the_window(names, key, per_request):
+    ctx = _ctx(WITH_STATS)
+    assert spantime.stat_per_request(ctx, names, key) == pytest.approx(
+        per_request)
+
+
+def test_no_stat_read_where_no_span_carries_it():
+    assert spantime.stat_per_request(_ctx(WITH_STATS), (spans.FILL,),
+                                     "bytes") is None
+    assert spantime.stat_per_request(_ctx(COLD + WARM), (spans.UPLOAD,),
+                                     "bytes") is None
+    # stats outside the window's requests are not read
+    outside = [("sparse.upload", 50000, 10, {"bytes": 4})]
+    assert spantime.stat_per_request(_ctx(COLD[:1] + outside),
+                                     (spans.UPLOAD,), "bytes") is None
 
 
 @pytest.mark.parametrize("name", READERS)

@@ -1,8 +1,10 @@
 """Trace reduction on a small recorded trace: busy union, idle share,
-attribution of operations to modules and of idle gaps to host spans."""
+attribution of operations to modules and of idle gaps to host spans,
+and the planes of several chips read apart."""
 import json
 import sys
 from pathlib import Path
+from types import SimpleNamespace as NS
 
 import pytest
 
@@ -112,3 +114,62 @@ def test_readers_return_nothing_where_nothing_ran(trace):
     for name in ("fill_device_ms.refill", "fill_roofline.refill",
                  "plan_device_ms.new", "radix_roofline.new"):
         assert harness.load_reader(BENCH, name).read(ctx) is None
+
+
+def _planes(raw, chips):
+    """The small trace as ``ProfileData`` planes: each of ``chips``
+    device planes holds its ops and modules, 100 ns later per chip; the
+    host plane holds the spans with stats on the first request."""
+    def ev(name, start, dur, stats=()):
+        return NS(name=name, start_ns=start, duration_ns=dur,
+                  stats=list(stats))
+
+    planes = [NS(name="/host:metadata", lines=[])]
+    for d in chips:
+        ops = [ev(n, s + 100 * d, t, [("hlo_module", m)] if m else [])
+               for n, s, t, m in raw["ops"]]
+        mods = [ev(n, s + 100 * d, t) for n, s, t in raw["modules"]]
+        planes.append(NS(name=f"/device:TPU:{d}", lines=[
+            NS(name="XLA Modules", events=mods),
+            NS(name="XLA Ops", events=ops)]))
+    host = [ev(n, s, t) for n, s, t in raw["host"]]
+    host.append(ev("sparse.upload", 600, 100, [("bytes", 4000)]))
+    host.append(ev("sparse.plan_key", 700, 100,
+                   [("bytes", 8000), ("hit", 0), ("hit", 1)]))
+    planes.append(NS(name="/host:CPU", lines=[NS(name="python",
+                                                 events=host)]))
+    return planes
+
+
+def test_planes_of_several_chips_read_apart(trace):
+    raw = json.loads(DATA.read_text())
+    one = tracereduce.from_planes(_planes(raw, [0]), [0])
+    assert one.ops == trace.ops
+    assert list(one.ops_by_device) == [0]
+    four = tracereduce.from_planes(_planes(raw, [0, 1, 2, 3]), [2, 0, 1, 3])
+    # ``ops`` is the first chip of the cell, as on one chip
+    assert list(four.ops_by_device) == [2, 0, 1, 3]
+    assert four.ops is four.ops_by_device[2]
+    assert four.ops_by_device[0] == one.ops
+    for d, ops in four.ops_by_device.items():
+        assert [o.start for o in ops] == [o.start + 100 * d for o in one.ops]
+        assert [o.module for o in ops] == [o.module for o in one.ops]
+    lo, hi = one.window()
+    assert tracereduce.busy(four.ops_by_device[3], lo, hi) == 4500
+
+
+def test_span_stats_kept_as_the_trace_holds_them():
+    raw = json.loads(DATA.read_text())
+    tr = tracereduce.from_planes(_planes(raw, [0]), [0])
+    (upload,) = tr.spans("sparse.upload")
+    (key,) = tr.spans("sparse.plan_key")
+    assert upload.stats == {"bytes": 4000}
+    # a stat set again (``set_metadata``) keeps its last value
+    assert key.stats == {"bytes": 8000, "hit": 1}
+    assert all(o.stats == {} for o in tr.ops)
+
+
+def test_a_chip_with_no_operation_is_an_error():
+    raw = json.loads(DATA.read_text())
+    with pytest.raises(ValueError, match="TPU:1"):
+        tracereduce.from_planes(_planes(raw, [0]), [0, 1])

@@ -4,8 +4,11 @@ The benchmark wraps its measured window in a host span ``bench.window``
 and every request in ``bench.request`` (``jax.profiler.TraceAnnotation``),
 so the device's operations and the harness's spans share the profiler's
 clock.  :func:`load` reads the ``.xplane.pb`` that
-``jax.profiler.stop_trace`` wrote; everything else works on plain lists
-of :class:`Event` so that it can be checked on a small recorded trace.
+``jax.profiler.stop_trace`` wrote: the operations of every chip of the
+cell, and the host's spans with the stats they carry (``bytes`` and
+``hit`` of ``sparse.plan_key``, ...).  Everything else works on plain
+lists of :class:`Event` so that it can be checked on a small recorded
+trace.
 
 Times are in nanoseconds.
 """
@@ -13,7 +16,11 @@ from __future__ import annotations
 
 import glob
 from pathlib import Path
+from types import MappingProxyType
 from typing import NamedTuple
+
+#: no stats, or no chips: the default of an event or a trace built by hand
+_EMPTY = MappingProxyType({})
 
 
 class Event(NamedTuple):
@@ -21,6 +28,9 @@ class Event(NamedTuple):
     start: float   # ns on the profiler's clock
     dur: float     # ns
     module: str = ""  # the XLA module (jitted program) a device op ran in
+    #: a host span's stats as the trace holds them (ints, floats,
+    #: strings; a stat set twice keeps its last value); empty for ops
+    stats: dict = _EMPTY
 
     @property
     def end(self) -> float:
@@ -28,8 +38,11 @@ class Event(NamedTuple):
 
 
 class Trace(NamedTuple):
-    ops: list          # device operations of one chip, [Event]
+    ops: list          # device operations of the cell's first chip, [Event]
     host: list         # host spans and annotations, [Event]
+    #: device id -> its operations, for every chip of the cell that was
+    #: read, in the cell's order (the first chip's list is ``ops``)
+    ops_by_device: dict = _EMPTY
 
     def spans(self, name: str) -> list:
         return [e for e in self.host if e.name == name]
@@ -53,8 +66,9 @@ def _stat(event, key):
     return None
 
 
-def load(logdir, device_id: int = 0) -> Trace:
-    """The operations of ``/device:TPU:<device_id>`` and the host's
+def load(logdir, device_ids=(0,)) -> Trace:
+    """The operations of ``/device:TPU:<id>`` for each of
+    ``device_ids`` (the cell's chips, first one first) and the host's
     spans, from the newest trace under ``logdir``."""
     import jax
 
@@ -63,34 +77,55 @@ def load(logdir, device_id: int = 0) -> Trace:
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {logdir}")
     data = jax.profiler.ProfileData.from_file(paths[-1])
-    ops, modules, host = [], [], []
-    device_plane = f"/device:TPU:{device_id}"
-    for plane in data.planes:
-        if plane.name == device_plane:
+    return from_planes(data.planes, device_ids)
+
+
+def from_planes(planes, device_ids=(0,)) -> Trace:
+    """:func:`load` on planes as ``jax.profiler.ProfileData`` gives
+    them (each with a ``name`` and ``lines`` of events)."""
+    planes = list(planes)
+    chips = {}
+    for d in device_ids:
+        name = f"/device:TPU:{d}"
+        ops, modules = [], []
+        for plane in planes:
+            if plane.name != name:
+                continue
             for line in plane.lines:
                 if line.name == OPS_LINE:
-                    for e in line.events:
-                        ops.append(Event(e.name, e.start_ns, e.duration_ns,
-                                         str(_stat(e, "hlo_module") or "")))
+                    ops.extend(Event(e.name, e.start_ns, e.duration_ns,
+                                     str(_stat(e, "hlo_module") or ""))
+                               for e in line.events)
                 elif line.name == MODULES_LINE:
                     modules.extend(Event(e.name, e.start_ns, e.duration_ns)
                                    for e in line.events)
-        elif plane.name.startswith("/host:"):
-            for line in plane.lines:
-                host.extend(Event(e.name, e.start_ns, e.duration_ns)
-                            for e in line.events if e.duration_ns > 0)
-    if not ops:
-        seen = {p.name: [ln.name for ln in p.lines] for p in data.planes}
-        raise ValueError(f"the trace holds no operation on {device_plane}; "
-                         f"planes and lines: {seen}")
-    return build(ops, modules, host)
+        if not ops:
+            seen = {p.name: [ln.name for ln in p.lines] for p in planes}
+            raise ValueError(f"the trace holds no operation on {name}; "
+                             f"planes and lines: {seen}")
+        chips[d] = (ops, modules)
+    host = [Event(e.name, e.start_ns, e.duration_ns, "", dict(e.stats))
+            for plane in planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.duration_ns > 0]
+    return build_chips(chips, host)
 
 
 def build(ops, modules, host) -> Trace:
-    """A :class:`Trace` from events as the trace file holds them: each
-    op named by its HLO instruction, and given its module."""
-    ops = [Event(hlo_name(o.name), o.start, o.dur, o.module) for o in ops]
-    return Trace(attach_modules(ops, modules), host)
+    """A :class:`Trace` of one chip (device 0) from events as the trace
+    file holds them: each op named by its HLO instruction, and given its
+    module."""
+    return build_chips({0: (ops, modules)}, host)
+
+
+def build_chips(chips: dict, host) -> Trace:
+    """:func:`build` for several chips: ``chips`` maps each device id,
+    in the cell's order, to its ``(ops, modules)``."""
+    by_device = {
+        d: attach_modules([Event(hlo_name(o.name), o.start, o.dur, o.module)
+                           for o in ops], modules)
+        for d, (ops, modules) in chips.items()}
+    return Trace(next(iter(by_device.values())), host, by_device)
 
 
 def hlo_name(label: str) -> str:
