@@ -38,7 +38,7 @@ PLAN_KEY = "sparse.plan_key"      # structure key, or the warm compare
 #                                   of the raw indices (bytes, hit)
 PLAN_CACHE = "sparse.plan_cache"  # plan LRU lookup
 PLAN = "sparse.plan"              # the symbolic phase, when it runs
-EXEC_CACHE = "sparse.exec_cache"  # executable LRU lookup
+EXEC_CACHE = "sparse.exec_cache"  # executable LRU lookup (hit)
 COMPILE = "sparse.compile"        # lowering and compiling, when it runs
 FILL = "sparse.fill"              # dispatch of the numeric fill
 

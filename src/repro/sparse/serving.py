@@ -11,14 +11,17 @@ module is that layer, sitting between the plan/fill core and callers:
 * **One locked cache core** (:mod:`repro.sparse.lru`): the ``sparse2``
   plan LRU, the SpGEMM product LRU and the executable tier below all
   ride the same thread-safe, metrics-instrumented implementation.
-* **AOT executable tier**: per hot structure, the numeric phase is
-  lowered and compiled **once** (``jax.jit(fill).lower(spec).compile()``)
-  and the compiled executable is replayed for every request — no
-  python re-trace, no jit-cache hashing of a pytree plan per call.
-  Value buffers are donated on backends that support donation (GPU/
-  TPU), so a request's input buffer is recycled into the output.
-  Covered ops: fill (``assemble``), batched fill (``assemble_many``),
-  SpGEMM (``multiply``) and SpMV (``spmv``).  All executables are
+* **AOT executable tier**: per shape, the numeric fill is lowered and
+  compiled **once** (``jax.jit(fill).lower(spec).compile()``), with
+  the plan's ``perm``/``slot`` as arguments, and the compiled
+  executable is replayed for every request on every structure of that
+  shape — no python re-trace, no jit-cache hashing of a pytree plan
+  per call.  Value buffers are donated on backends that support
+  donation (GPU/TPU), so a request's input buffer is recycled into the
+  output.  Covered ops: fill (``assemble``), batched fill
+  (``assemble_many``), SpGEMM (``multiply``) and SpMV (``spmv``); the
+  last two are still compiled per structure, with its index arrays
+  embedded.  All executables are
   lowered from exactly the code the uncached paths run, so results are
   bit-identical to ``fsparse``/``ops.matmul`` dispatch.
 * **Persistent warm restarts**: plan/product cache entries are written
@@ -325,6 +328,26 @@ def load_caches(cache_dir) -> tuple:
     return (counts["plan"], counts["product"])
 
 
+def _served_fill(pat: SparsePattern, *, batched: bool = False,
+                 donate: bool = False):
+    """The served fill, jitted as a function of ``(perm, slot, vals)``.
+
+    :meth:`SparsePattern.scatter` on ``pat`` with its ``perm`` and
+    ``slot`` replaced by the arguments, so results are bit-identical to
+    ``fsparse``.  ``pat`` lends the static geometry (``nzmax``,
+    ``accum``); none of its arrays enters the program.  So one
+    executable serves every plan of its shape, its size does not grow
+    with L, and XLA folds nothing derived from the plan at compile
+    time.  Only ``vals`` (argument 2) is donated: ``perm``/``slot``
+    belong to the cached plan and serve every later request.
+    """
+    def scatter(perm, slot, vals):  # the trace's module: ``jit_scatter``
+        return dataclasses.replace(pat, perm=perm, slot=slot).scatter(vals)
+
+    fn = jax.vmap(scatter, in_axes=(None, None, 0)) if batched else scatter
+    return jax.jit(fn, donate_argnums=(2,) if donate else ())
+
+
 # ---------------------------------------------------------------------------
 # The service
 # ---------------------------------------------------------------------------
@@ -456,33 +479,45 @@ class PlanService:
         # the tuning fingerprint is folded into every executable key:
         # a re-tune (new measured table) retires stale executables
         # lowered under the old policy instead of replaying them.
+        hit = 1
+
         def compile_():
+            nonlocal hit
+            hit = 0
             with span(COMPILE):
                 return build()
 
-        with span(EXEC_CACHE):
-            return self._execs.get_or_create(
+        with span(EXEC_CACHE) as s:
+            exe = self._execs.get_or_create(
                 ekey + (tuning.tuning_fingerprint(),), compile_
             )
+            s.set_metadata(hit=hit)
+            return exe
 
-    def _fill_executable(self, key, pat: SparsePattern, vals_shape,
-                         vals_dtype, batch: int | None = None):
-        """Compiled numeric fill for one plan (optionally vmap-batched).
+    def _fill_executable(self, pat: SparsePattern, vals_shape, vals_dtype,
+                         batch: int | None = None):
+        """Compiled numeric fill for one shape (optionally vmap-batched),
+        called as ``fill(pat.perm, pat.slot, vals)``.
 
-        Lowered from :meth:`SparsePattern.scatter` — the exact code the
-        jit path runs — so replay is bit-identical to ``fsparse``.
+        Keyed by ``(L, nzmax, accum, dtype, batch)``, not by structure:
+        every plan of that shape shares the executable (see
+        :func:`_served_fill`).
         """
         dtype = jnp.dtype(vals_dtype)
-        ekey = ("fill", key, dtype.str, None if batch is None else int(batch))
+        batch = None if batch is None else int(batch)
+        ekey = ("fill", int(vals_shape[0]), pat.nzmax, pat.accum, dtype.str,
+                batch)
 
         def build():
-            fn = pat.scatter if batch is None else jax.vmap(pat.scatter)
             shape = tuple(vals_shape) if batch is None \
-                else (int(batch),) + tuple(vals_shape)
-            jitted = jax.jit(
-                fn, donate_argnums=(0,) if self.donate else ()
-            )
-            return jitted.lower(jax.ShapeDtypeStruct(shape, dtype)).compile()
+                else (batch,) + tuple(vals_shape)
+            fill = _served_fill(pat, batched=batch is not None,
+                                donate=self.donate)
+            return fill.lower(
+                jax.ShapeDtypeStruct(pat.perm.shape, pat.perm.dtype),
+                jax.ShapeDtypeStruct(pat.slot.shape, pat.slot.dtype),
+                jax.ShapeDtypeStruct(shape, dtype),
+            ).compile()
 
         return self._aot(ekey, build)
 
@@ -509,9 +544,9 @@ class PlanService:
                     return pat.assemble(vals)
             maybe_validate_pattern(pat, subject="PlanService.assemble")
             self._persist("plan", key, pat)
-            fill = self._fill_executable(key, pat, vals.shape, vals.dtype)
+            fill = self._fill_executable(pat, vals.shape, vals.dtype)
             with span(FILL):
-                data = fill(vals)
+                data = fill(pat.perm, pat.slot, vals)
             return self._wrap(pat, data)
 
     def assemble_many(self, requests, *, method: str | None = None,
@@ -549,14 +584,15 @@ class PlanService:
                 self._persist("plan", key, pat)
                 vals0 = looked[idxs[0]][2]
                 if len(idxs) == 1:
-                    fill = self._fill_executable(key, pat, vals0.shape,
+                    fill = self._fill_executable(pat, vals0.shape,
                                                  vals0.dtype)
-                    results[idxs[0]] = self._wrap(pat, fill(vals0))
+                    results[idxs[0]] = self._wrap(
+                        pat, fill(pat.perm, pat.slot, vals0))
                     continue
-                fill = self._fill_executable(key, pat, vals0.shape,
-                                             vals0.dtype, batch=len(idxs))
+                fill = self._fill_executable(pat, vals0.shape, vals0.dtype,
+                                             batch=len(idxs))
                 stacked = jnp.stack([looked[i][2] for i in idxs])
-                data_b = fill(stacked)
+                data_b = fill(pat.perm, pat.slot, stacked)
                 for b, i in enumerate(idxs):
                     results[i] = self._wrap(pat, data_b[b])
             return results
@@ -570,14 +606,15 @@ class PlanService:
 
         Runs :func:`repro.sparse.plan_update` (merge-forward delta
         re-planning through the shared plan LRU), then reconciles the
-        serving tiers: AOT executables bound to the *old* structure —
-        its fill, and any SpGEMM/SpMV executables lowered against its
-        index arrays — are retired from the executable LRU, persisted
-        entries for the old structure are unlinked from ``cache_dir``,
-        and only the updated structure's fill is (re-)lowered.
+        serving tiers: SpGEMM/SpMV executables lowered against the *old*
+        structure's index arrays are retired from the executable LRU,
+        and persisted entries for the old structure are unlinked from
+        ``cache_dir``.  Fill executables belong to a shape, not a
+        structure, so none is retired, and the updated structure's fill
+        compiles only if no structure of its shape has been served yet.
         Executables for unrelated structures are untouched, so a warm
-        service absorbs a delta at the cost of one merge + one fill
-        compile, not a cache flush.
+        service absorbs a delta at the cost of one merge, not a cache
+        flush.
 
         Returns the assembled updated matrix (bit-identical to a cold
         :meth:`assemble` over the concatenated surviving + delta
@@ -597,8 +634,6 @@ class PlanService:
 
                 def _stale(ekey) -> bool:
                     kind = ekey[0]
-                    if kind == "fill":
-                        return ekey[1] == res.old_key
                     if kind == "multiply":
                         return old_sk in (ekey[1][0], ekey[1][1])
                     if kind == "spmv":
@@ -610,10 +645,10 @@ class PlanService:
             maybe_validate_pattern(res.pattern,
                                    subject="PlanService.update_structure")
             self._persist("plan", res.key, res.pattern)
-            fill = self._fill_executable(res.key, res.pattern,
-                                         res.coo.vals.shape,
+            pat = res.pattern
+            fill = self._fill_executable(pat, res.coo.vals.shape,
                                          res.coo.vals.dtype)
-            return self._wrap(res.pattern, fill(res.coo.vals))
+            return self._wrap(pat, fill(pat.perm, pat.slot, res.coo.vals))
 
     def multiply(self, A, B, *, method: str | None = None,
                  nzmax: int | None = None,

@@ -431,6 +431,92 @@ def test_service_donate_defaults_off_on_cpu():
 
 
 # ---------------------------------------------------------------------------
+# The fill executable belongs to a shape: the plan is an argument
+# ---------------------------------------------------------------------------
+def test_service_fill_executable_shared_across_structures():
+    n, L = 60, 500
+    svc = PlanService()
+    for seed in (41, 42):   # two structures of equal (L, nzmax)
+        ii, jj, ss = _triplet(n, L, seed=seed)
+        _assert_same_csc(svc.assemble(ii, jj, ss, (n, n), L),
+                         fsparse(ii, jj, ss, (n, n), L))
+    info = svc.stats()["exec"]
+    assert info["insertions"] == 1 and info["size"] == 1
+    assert info["hits"] == 1
+
+
+@pytest.mark.parametrize("batched", [False, True])
+def test_served_fill_lowers_no_plan_sized_constant(batched):
+    """The lowered program does not grow with L: nothing of the plan
+    is embedded in it."""
+    from repro.sparse import plan
+    from repro.sparse.serving import _served_fill
+
+    def text(L):
+        rng = np.random.default_rng(L)
+        pat = plan(jnp.asarray(rng.integers(0, 300, L), jnp.int32),
+                   jnp.asarray(rng.integers(0, 300, L), jnp.int32),
+                   (300, 300))
+        vals = (3, L) if batched else (L,)
+        return _served_fill(pat, batched=batched).lower(
+            pat.perm, pat.slot, jax.ShapeDtypeStruct(vals, jnp.float32),
+        ).as_text()
+
+    assert len(text(100_000)) < 2 * len(text(10_000))
+
+
+def test_service_donation_keeps_the_plan_arrays():
+    from repro.sparse.matlab import _lookup_values
+
+    n, L = 50, 400
+    ii, jj, ss = _triplet(n, L, seed=43)
+    svc = PlanService(donate=True)
+    for k in (1, 2):
+        svc.assemble(ii, jj, ss * k, (n, n))
+    _, pat, _ = _lookup_values(ii, jj, ss, (n, n))
+    assert not pat.perm.is_deleted() and not pat.slot.is_deleted()
+    _assert_same_csc(svc.assemble(ii, jj, ss * 3, (n, n)),
+                     fsparse(ii, jj, ss * 3, (n, n)))
+
+
+@pytest.fixture(scope="module")
+def exec_cache_spans(tmp_path_factory):
+    """The ``sparse.exec_cache`` host spans of three ``assemble``
+    requests on one structure (cold, warm, warm), from a CPU trace."""
+    import glob
+
+    from repro.core import spans
+
+    ii, jj, ss = _triplet(30, 200, seed=44)
+    plan_cache_clear()
+    svc = PlanService()
+    logdir = tmp_path_factory.mktemp("trace")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(logdir), profiler_options=opts)
+    try:
+        for k in range(3):
+            svc.assemble(ii, jj, ss + k, (30, 30)).data.block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+        plan_cache_clear()
+    (path,) = glob.glob(str(logdir / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    events = sorted(
+        (e.start_ns, dict(e.stats))
+        for plane in data.planes if plane.name.startswith("/host:")
+        for line in plane.lines for e in line.events
+        if e.name == spans.EXEC_CACHE)
+    return [stats for _, stats in events]
+
+
+@pytest.mark.parametrize("request_no,hit", [(0, 0), (1, 1), (2, 1)])
+def test_exec_cache_span_carries_hit(exec_cache_spans, request_no, hit):
+    assert len(exec_cache_spans) == 3
+    assert exec_cache_spans[request_no]["hit"] == hit
+
+
+# ---------------------------------------------------------------------------
 # update_structure: delta absorption without a cache flush (ISSUE 7)
 # ---------------------------------------------------------------------------
 def _delta(n: int, Ld: int, seed: int = 100):
@@ -478,9 +564,11 @@ def test_service_update_retires_only_affected_executables():
 
     svc.update_structure(ii_a, jj_a, ss_a, ai, aj, av, (n, n), cap)
     mid = svc.stats()["exec"]
-    # fill A retired (not evicted), new fill lowered once: same size,
-    # exactly one more insertion, no evictions
-    assert mid["size"] == 3
+    # fill executables belong to a shape, not a structure: A's stays
+    # (another structure of its shape may use it), the updated shape's
+    # fill is lowered once: one more entry, one more insertion, no
+    # evictions
+    assert mid["size"] == 4
     assert mid["insertions"] == before["insertions"] + 1
     assert mid["evictions"] == 0
 
@@ -495,7 +583,7 @@ def test_service_update_retires_only_affected_executables():
     svc.update_structure(ii_a, jj_a, ss_a, ai, aj, av, (n, n), cap)
     final = svc.stats()["exec"]
     assert final["insertions"] == after["insertions"]
-    assert final["size"] == 3
+    assert final["size"] == 4
 
 
 def test_service_update_retires_spgemm_executables_and_products():
@@ -511,9 +599,10 @@ def test_service_update_retires_spgemm_executables_and_products():
     assert product_cache_info()["size"] == 1
 
     svc.update_structure(ii, jj, ss, ai, aj, av, (n, n), cap)
-    # multiply executable referenced A's old structure: retired
+    # multiply executable referenced A's old structure: retired; the
+    # fills of the old and the updated shape both stay
     ekinds = sorted(k[0] for k, _ in svc._execs.items())
-    assert ekinds == ["fill"]
+    assert ekinds == ["fill", "fill"]
     # dependent product plan purged lazily at the next product lookup
     A0 = fsparse(ii, jj, ss, (n, n), nzmax=cap)
     C2 = svc.multiply(A0, B)

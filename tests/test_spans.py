@@ -13,6 +13,7 @@ from repro.core import spans
 from repro.sparse import (PlanService, cached_product_plan, fsparse, ops,
                           plan, plan_cache_clear)
 from repro.sparse.pattern import _merge_sorted_streams
+from repro.sparse.serving import _served_fill
 
 L, M, N = 600, 40, 30
 #: the stage spans of a cold request, in the order it crosses them
@@ -116,7 +117,7 @@ def _programs():
     S = pat.assemble(jnp.ones(L))
     return {
         "plan": plan.lower(rows, cols, shape=(N, N)),
-        "fill": jax.jit(pat.scatter).lower(jnp.ones(L)),
+        "fill": _served_fill(pat).lower(pat.perm, pat.slot, jnp.ones(L)),
         "merge": _merge_sorted_streams.lower(
             pat.srows, pat.scols, pat.perm, rows[:7], cols[:7],
             jnp.int32(L), M=N, N=N, nzmax=L + 7, method=None,

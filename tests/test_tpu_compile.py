@@ -25,6 +25,7 @@ from repro.kernels.radix_sort.radix_sort import (
 )
 from repro.sparse import tuning
 from repro.sparse.pattern import SparsePattern, pattern_from_perm
+from repro.sparse.serving import _served_fill
 
 L = 40_000_000
 M = N = 800_000
@@ -124,13 +125,16 @@ def test_plan_scopes_keep_kernel_names_for_v5e(one_chip, monkeypatch):
 
 
 def test_served_fill_compiles_for_v5e(one_chip):
-    """The executable ``PlanService`` replays per request:
-    ``SparsePattern.scatter`` (an XLA gather + scatter-add)."""
+    """The executable ``PlanService`` replays per request, from the
+    builder it uses: ``SparsePattern.scatter``'s body (an XLA gather +
+    scatter-add) over ``(perm, slot, vals)``, the values donated."""
     vec = _shape(one_chip, (L,))
     pat = SparsePattern(perm=vec, slot=vec, indices=vec,
                         indptr=_shape(one_chip, (N + 1,)),
                         nnz=_shape(one_chip, ()), srows=vec, scols=vec,
                         shape=(M, N))
     vals = _shape(one_chip, (L,), jnp.float32)
-    compiled = jax.jit(lambda p, v: p.scatter(v)).lower(pat, vals).compile()
+    fill = _served_fill(pat, donate=True)
+    compiled = fill.lower(vec, vec, vals).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+    assert compiled.as_text().startswith("HloModule jit_scatter,")
